@@ -14,10 +14,12 @@ constants, both multiplying terms that are exactly zero at the INT8 anchor
     vs (w+a)/16 across the kernel corners.
 
 Measurement: each kernel corner is lowered through ``jax.jit`` in Pallas
-interpret mode (``repro.kernels._compat.interpret_default`` — runs on CI
-without a TPU) at a grid-(1,..) shape so XLA's ``cost_analysis()`` FLOP /
-"bytes accessed" counts are exact (no while-loop body undercount; see
-launch/dryrun.py). Corners cover three kernels x operand widths:
+interpret mode, on every backend, at a grid-(1,..) shape so XLA's
+``cost_analysis()`` FLOP / "bytes accessed" counts are exact (no
+while-loop body undercount; see launch/dryrun.py). The fitted constants
+are priced numbers, so they must not change with the machine: the counts
+come from the interpret-mode program, never from a native TPU compile.
+Corners cover three kernels x operand widths:
 
     int8_matmul     w8  a8    (the INT8 anchor)
     depthwise_conv  bf16/fp32 (same kernel at 16- and 32-bit operands)
@@ -79,19 +81,16 @@ def _cost(lowered) -> Dict[str, float]:
             "bytes": float(ca.get("bytes accessed", 0.0))}
 
 
-def run_samples(interpret: Optional[bool] = None) -> List[CalSample]:
+def run_samples() -> List[CalSample]:
     """Lower, cost-analyze and execute every calibration corner."""
     import jax
     import jax.numpy as jnp
 
     from repro.kernels import ref
-    from repro.kernels._compat import interpret_default
     from repro.kernels.depthwise_conv import depthwise_conv3x3_padded
     from repro.kernels.int8_matmul import int8_matmul
     from repro.kernels.quantize import quantize_rows
 
-    if interpret is None:
-        interpret = interpret_default()
     rng = np.random.default_rng(20260808)
     out: List[CalSample] = []
 
@@ -101,8 +100,8 @@ def run_samples(interpret: Optional[bool] = None) -> List[CalSample]:
     b = jnp.asarray(rng.integers(-127, 128, (K, N), dtype=np.int8))
     sa = jnp.asarray(rng.random(M, dtype=np.float32))
     sb = jnp.asarray(rng.random(N, dtype=np.float32))
-    c = _cost(int8_matmul.lower(a, b, sa, sb, interpret=interpret))
-    got = int8_matmul(a, b, sa, sb, interpret=interpret)
+    c = _cost(int8_matmul.lower(a, b, sa, sb, interpret=True))
+    got = int8_matmul(a, b, sa, sb, interpret=True)
     err = float(jnp.max(jnp.abs(got - ref.int8_matmul(a, b, sa, sb))))
     out.append(CalSample("int8_matmul", "int8", 8, 8, M * N * K,
                          c["flops"], c["bytes"],
@@ -116,9 +115,8 @@ def run_samples(interpret: Optional[bool] = None) -> List[CalSample]:
     for prec, dt, bits in (("bf16", jnp.bfloat16, 16), ("fp32", jnp.float32, 32)):
         xd, wd = x.astype(dt), w.astype(dt)
         x_pad = jnp.pad(xd, ((0, 0), (1, 1), (1, 1), (0, 0)))
-        c = _cost(depthwise_conv3x3_padded.lower(x_pad, wd,
-                                                 interpret=interpret))
-        got = depthwise_conv3x3_padded(x_pad, wd, interpret=interpret)
+        c = _cost(depthwise_conv3x3_padded.lower(x_pad, wd, interpret=True))
+        got = depthwise_conv3x3_padded(x_pad, wd, interpret=True)
         err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
         elems = (B * (H + 2) * (W + 2) * C + 9 * C + B * H * W * C)
         out.append(CalSample("depthwise_conv", prec, bits, bits,
@@ -128,8 +126,8 @@ def run_samples(interpret: Optional[bool] = None) -> List[CalSample]:
     # --- quantize (f32 in, int8 codes out), grid (1,) ---------------------
     M, N = 256, 512
     q = jnp.asarray(rng.random((M, N), dtype=np.float32))
-    c = _cost(quantize_rows.lower(q, interpret=interpret))
-    codes, scales = quantize_rows(q, interpret=interpret)
+    c = _cost(quantize_rows.lower(q, interpret=True))
+    codes, scales = quantize_rows(q, interpret=True)
     rc, rs = ref.quantize_rows(q)
     err = max(float(jnp.max(jnp.abs(codes.astype(jnp.int32)
                                     - rc.astype(jnp.int32)))),
@@ -176,9 +174,9 @@ def fit_constants(samples: Sequence[CalSample]):
     return constants, residuals
 
 
-def run_calibration(interpret: Optional[bool] = None) -> Dict:
+def run_calibration() -> Dict:
     import jax
-    samples = run_samples(interpret=interpret)
+    samples = run_samples()
     constants, residuals = fit_constants(samples)
     return {
         "meta": {"generator": "repro.calibrate.harness",
@@ -191,17 +189,15 @@ def run_calibration(interpret: Optional[bool] = None) -> Dict:
     }
 
 
-def write_calibrated(path: str = CALIB_PATH,
-                     interpret: Optional[bool] = None) -> Dict:
-    data = run_calibration(interpret=interpret)
+def write_calibrated(path: str = CALIB_PATH) -> Dict:
+    data = run_calibration()
     with open(path, "w") as f:
         json.dump(data, f, indent=1, sort_keys=True)
         f.write("\n")
     return data
 
 
-def check(path: str = CALIB_PATH, interpret: Optional[bool] = None,
-          data: Optional[Dict] = None) -> List[str]:
+def check(path: str = CALIB_PATH, data: Optional[Dict] = None) -> List[str]:
     """Re-run the harness against the checked-in fit; return failures
     (empty list == green). The calibrate-smoke CI gate. Pass ``data`` to
     gate an already-computed ``run_calibration`` result instead of
@@ -209,7 +205,7 @@ def check(path: str = CALIB_PATH, interpret: Optional[bool] = None,
     with open(path) as f:
         baseline = json.load(f)
     if data is None:
-        data = run_calibration(interpret=interpret)
+        data = run_calibration()
     fails: List[str] = []
     for name, got in data["residuals"].items():
         ref_val = baseline["residuals"].get(name)

@@ -11,17 +11,20 @@ Halo handling: rather than overlapping block reads (not expressible with
 blocked index maps), the pre-padded input is passed as THREE row-shifted
 views (XLA slices of one buffer); each grid step then reads aligned
 (th, W+2, bc) tiles and writes a clean (th, W, bc) tile.
+
+Any (H, C) runs: the row tile shrinks to a divisor of H, and a channel
+count with no 128-multiple divisor (MobileNetV2's 144, 576, 960) takes
+all of C as one lane block, which the TPU accepts as a full-extent block.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams
 
 
 def _kernel(x0_ref, x1_ref, x2_ref, w_ref, o_ref, *, wout: int):
@@ -42,8 +45,9 @@ def depthwise_conv3x3_padded(x_pad: jax.Array, w: jax.Array, *,
     """x_pad: (B, H+2, W+2, C) pre-padded by 1px; w: (3,3,C) -> (B,H,W,C)."""
     B, Hp, Wp, C = x_pad.shape
     H, W = Hp - 2, Wp - 2
-    th, bc = min(th, H), min(bc, C)
-    assert H % th == 0 and C % bc == 0, (H, th, C, bc)
+    th = math.gcd(H, th)
+    bc = next((b for b in range(min(bc, C) // 128 * 128, 0, -128)
+               if C % b == 0), C)
 
     x0 = x_pad[:, 0:H]                                  # row r   (top)
     x1 = x_pad[:, 1:H + 1]                              # row r+1 (mid)
@@ -57,7 +61,7 @@ def depthwise_conv3x3_padded(x_pad: jax.Array, w: jax.Array, *,
                   pl.BlockSpec((3, 3, bc), lambda b, i, c: (0, 0, c))],
         out_specs=pl.BlockSpec((1, th, W, bc), lambda b, i, c: (b, i, 0, c)),
         out_shape=jax.ShapeDtypeStruct((B, H, W, C), x_pad.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(x0, x1, x2, w)

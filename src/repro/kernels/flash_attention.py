@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -68,7 +66,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """q,k,v: (B,H,S,D) -> (B,H,S,D); fp32 softmax, dtype-preserving out."""
     B, H, S, D = q.shape
     bq, bk = min(bq, S), min(bk, S)
-    assert S % bq == 0 and S % bk == 0, (S, bq, bk)
+    if S % bq or S % bk:
+        raise ValueError(f"sequence {S} is not a multiple of the "
+                         f"({bq}, {bk}) blocks")
     nq, nk = S // bq, S // bk
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * H, S, D)
@@ -91,7 +91,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

@@ -5,7 +5,9 @@ computation; we re-tile for VMEM instead of PE scratchpads).
 Tiling: grid (M/bm, N/bn, K/bk), K innermost ("arbitrary" = sequential) so a
 VMEM int32 scratch accumulates across K-steps; the dequant epilogue fires on
 the last K-step, keeping the int32->f32 conversion out of HBM traffic.
-Block shapes default to MXU-aligned (128, 128) tiles.
+Block shapes default to MXU-aligned (128, 128) tiles. The scales enter as
+2-D (M, 1) / (1, N) columns: a 1-D scale block does not match the TPU's
+HBM layout of a 1-D array once the grid has more than one block.
 """
 from __future__ import annotations
 
@@ -15,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams
 
 
 def _kernel(a_ref, b_ref, as_ref, bs_ref, o_ref, acc_ref, *, nk: int):
@@ -32,7 +32,7 @@ def _kernel(a_ref, b_ref, as_ref, bs_ref, o_ref, acc_ref, *, nk: int):
     @pl.when(pl.program_id(2) == nk - 1)
     def _epilogue():
         o_ref[...] = (acc_ref[...].astype(jnp.float32)
-                      * as_ref[...][:, None] * bs_ref[...][None, :])
+                      * as_ref[...] * bs_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -42,9 +42,12 @@ def int8_matmul(a: jax.Array, b: jax.Array, a_scale: jax.Array,
     """a:(M,K) int8, b:(K,N) int8, a_scale:(M,), b_scale:(N,) -> (M,N) f32."""
     M, K = a.shape
     K2, N = b.shape
-    assert K == K2, (a.shape, b.shape)
+    if K != K2:
+        raise ValueError(f"inner dims differ: {a.shape} x {b.shape}")
     bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
+    if M % bm or N % bn or K % bk:
+        raise ValueError(f"(M, N, K)=({M}, {N}, {K}) is not a multiple of "
+                         f"the ({bm}, {bn}, {bk}) blocks")
     nk = K // bk
 
     return pl.pallas_call(
@@ -53,13 +56,14 @@ def int8_matmul(a: jax.Array, b: jax.Array, a_scale: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bm,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a, b, a_scale.astype(jnp.float32), b_scale.astype(jnp.float32))
+    )(a, b, a_scale.astype(jnp.float32).reshape(M, 1),
+      b_scale.astype(jnp.float32).reshape(1, N))

@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512").strip()
-os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")   # silence SPMD warnings
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production mesh, WITHOUT allocating a single parameter.
 
@@ -17,6 +12,7 @@ the HLO — the inputs to EXPERIMENTS.md §Dry-run and §Roofline.
 import argparse
 import dataclasses
 import json
+import os
 import sys
 import time
 from functools import partial
@@ -27,10 +23,21 @@ import jax.numpy as jnp
 from repro.configs import LM_ARCHS, SHAPES, cell_is_runnable, get_config
 from repro.core import roofline as rl
 from repro.launch import mesh as mesh_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.models.params import abstract, logical_axes
 from repro.sharding import fix_divisibility, spec_tree, use_mesh
 from repro.train import optim
+
+
+def force_host_devices():
+    """Give the CPU backend 512 virtual devices, enough for the multi-pod
+    mesh. XLA reads the flag when the backend starts, so call this before
+    anything asks JAX for its devices."""
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=512"
+                               ).strip()
+    os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")   # silence SPMD warnings
 
 
 def _opt_state_abstract(params_abs):
@@ -200,6 +207,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main():
+    force_host_devices()
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default=None)
     p.add_argument("--shape", default=None)

@@ -12,6 +12,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs import SHAPES, get_config
 from repro.configs.base import ModelConfig
@@ -19,10 +20,19 @@ from repro.models import lm
 from repro.models.params import ParamDef, abstract, logical_axes
 
 
+def _auto_mesh(shape, axes, devices=None):
+    """Mesh whose axes are ``Auto``: the logical-axis rules in
+    ``repro.sharding`` place arrays with sharding constraints, which JAX
+    accepts only on ``Auto`` axes (``jax.make_mesh`` defaults to
+    ``Explicit``)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh_from_devices(devices=None, model_parallel: int = 16):
@@ -30,7 +40,7 @@ def make_mesh_from_devices(devices=None, model_parallel: int = 16):
     devices = devices if devices is not None else jax.devices()
     n = len(devices)
     mp = math.gcd(model_parallel, n)
-    return jax.make_mesh((n // mp, mp), ("data", "model"), devices=devices)
+    return _auto_mesh((n // mp, mp), ("data", "model"), devices=devices)
 
 
 # ---------------------------------------------------------------------------

@@ -12,12 +12,14 @@ import jax
 import numpy as np
 
 from repro.configs import get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.models.params import materialize
 from repro.serve.engine import Request, ServeEngine
 
 
 def main():
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="llama3.2-1b")
     p.add_argument("--requests", type=int, default=8)

@@ -16,11 +16,12 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, get_smoke
 from repro.data import synthetic
 from repro.launch import mesh as mesh_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.models.params import abstract, logical_axes, materialize
 from repro.sharding import fix_divisibility, spec_tree, use_mesh
@@ -29,7 +30,46 @@ from repro.train import compress as compress_mod
 from repro.train import optim
 
 
+def make_train_step(cfg, lr_fn, out_shardings, compress_grads: bool = False):
+    """Jitted (params, opt_state, err, batch, step) -> (..., loss); call it
+    inside ``use_mesh`` so the model's sharding annotations bind.
+    ``out_shardings`` (from ``shard_train_state``) pins each step's output
+    to the layout it was given, so the next step reuses the compiled
+    program instead of compiling one for XLA's own choice of layout."""
+    def train_step(params, opt_state, err, batch, step):
+        (loss, metrics), grads = jax.value_and_grad(
+            lm.lm_loss, has_aux=True, argnums=1)(cfg, params, batch)
+        if compress_grads:
+            q, s, err = compress_mod.compress(grads, err)
+            grads = compress_mod.decompress(q, s)
+        grads, gnorm = optim.clip_by_global_norm(grads, 1.0)
+        params, opt_state = optim.adamw_update(
+            grads, opt_state, params, lr=lr_fn(step))
+        return params, opt_state, err, loss
+
+    return jax.jit(train_step, donate_argnums=(0, 1, 2),
+                   out_shardings=out_shardings)
+
+
+def shard_train_state(pdefs, params, mesh, compress_grads: bool = False):
+    """Place ``params`` by the logical-axis rules and build the AdamW moments
+    (and the compression error) with the same shardings, so no device holds
+    a full f32 copy. Returns (params, opt_state, err, out_shardings), the
+    last for ``make_train_step``."""
+    shardings = fix_divisibility(
+        spec_tree(logical_axes(pdefs), mesh), abstract(pdefs))
+    scalar = NamedSharding(mesh, P())
+    opt_sh = optim.AdamWState(shardings, shardings, scalar)
+    err_sh = shardings if compress_grads else scalar
+    params = jax.device_put(params, shardings)
+    opt_state = jax.jit(optim.adamw_init, out_shardings=opt_sh)(params)
+    err = (jax.jit(compress_mod.init_error, out_shardings=err_sh)(params)
+           if compress_grads else jax.device_put(jnp.zeros(()), scalar))
+    return params, opt_state, err, (shardings, opt_sh, err_sh, scalar)
+
+
 def main():
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--smoke", action="store_true")
@@ -56,28 +96,11 @@ def main():
     lr_fn = optim.cosine_schedule(a.lr, warmup=max(1, a.steps // 10),
                                   total=a.steps)
 
-    def train_step(params, opt_state, err, batch, step):
-        (loss, metrics), grads = jax.value_and_grad(
-            lm.lm_loss, has_aux=True, argnums=1)(cfg, params, batch)
-        if a.compress_grads:
-            q, s, err = compress_mod.compress(grads, err)
-            grads = compress_mod.decompress(q, s)
-        grads, gnorm = optim.clip_by_global_norm(grads, 1.0)
-        params, opt_state = optim.adamw_update(
-            grads, opt_state, params, lr=lr_fn(step))
-        return params, opt_state, err, loss
-
     with use_mesh(mesh):
-        params = materialize(pdefs, jax.random.key(0))
-        shardings = fix_divisibility(
-            spec_tree(logical_axes(pdefs), mesh), abstract(pdefs))
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, s) if s else x, params, shardings,
-            is_leaf=lambda x: hasattr(x, "shape") and not isinstance(x, dict))
-        opt_state = optim.adamw_init(params)
-        err = (compress_mod.init_error(params) if a.compress_grads
-               else jnp.zeros(()))
-        step_fn = jax.jit(train_step, donate_argnums=(0, 1, 2))
+        params, opt_state, err, out_sh = shard_train_state(
+            pdefs, materialize(pdefs, jax.random.key(0)), mesh,
+            a.compress_grads)
+        step_fn = make_train_step(cfg, lr_fn, out_sh, a.compress_grads)
 
         start = 0
         if a.ckpt_dir and ckpt_mod.latest_step(a.ckpt_dir) is not None:

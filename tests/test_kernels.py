@@ -137,32 +137,52 @@ def test_quantize_roundtrip_property(rng):
 
 
 # ---------------------------------------------------------------------------
-# interpret-mode knob (kernels/_compat.py): the CI-without-TPU fallback
+# interpret mode follows the backend; shapes off the tiling raise
 # ---------------------------------------------------------------------------
 
 def test_interpret_default_env_override(monkeypatch):
-    from repro.kernels import _compat
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
-    assert _compat.interpret_default() is True
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "off")
-    assert _compat.interpret_default() is False
-    monkeypatch.delenv("REPRO_KERNEL_INTERPRET")
-    # unset: backend autodetect (CPU in this container -> interpret)
-    assert _compat.interpret_default() == (jax.default_backend() == "cpu")
+    """No environment variable can switch the kernels' mode: interpret
+    exactly when the backend is the CPU."""
+    for value in ("1", "0", "true", "off"):
+        monkeypatch.setenv("REPRO_KERNEL_INTERPRET", value)
+        assert ops.interpret_default() == (jax.default_backend() == "cpu")
 
 
-def test_kernel_parity_through_interpret_knob(rng, monkeypatch):
+def test_kernel_parity_through_interpret_knob(rng):
     """int8_matmul / depthwise_conv vs the ref.py oracles with interpret
-    mode FORCED via the knob (the calibration-harness execution path)."""
-    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "true")
+    mode passed explicitly (the calibration-harness execution path)."""
+    from repro.kernels.depthwise_conv import depthwise_conv3x3_padded
+    from repro.kernels.int8_matmul import int8_matmul
     a = jnp.asarray(rng.integers(-127, 128, (128, 256)), jnp.int8)
     b = jnp.asarray(rng.integers(-127, 128, (256, 128)), jnp.int8)
     sa = jnp.asarray(rng.uniform(1e-3, 1e-2, (128,)), jnp.float32)
     sb = jnp.asarray(rng.uniform(1e-3, 1e-2, (128,)), jnp.float32)
-    np.testing.assert_allclose(ops.int8_matmul(a, b, sa, sb),
+    np.testing.assert_allclose(int8_matmul(a, b, sa, sb, interpret=True),
                                ref.int8_matmul(a, b, sa, sb), rtol=1e-6)
     x = jnp.asarray(rng.normal(size=(1, 16, 16, 128)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(3, 3, 128)), jnp.float32)
+    x_pad = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
+    np.testing.assert_allclose(
+        depthwise_conv3x3_padded(x_pad, w, interpret=True),
+        ref.depthwise_conv3x3(x, w), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape", [(2, 12, 10, 144), (1, 6, 5, 576)])
+def test_depthwise_untiled_channels_run_the_kernel(rng, shape):
+    """MobileNetV2's 144/576-channel layers (no 128-multiple divisor) and
+    row counts off the 8-row tile run the Pallas kernel, not the oracle."""
+    x = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(3, 3, shape[-1])), jnp.float32)
+    assert "pallas_call" in str(jax.make_jaxpr(ops.depthwise_conv3x3)(x, w))
     np.testing.assert_allclose(ops.depthwise_conv3x3(x, w),
                                ref.depthwise_conv3x3(x, w),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_untileable_shape_raises(rng):
+    a = jnp.zeros((200, 128), jnp.int8)
+    b = jnp.zeros((128, 128), jnp.int8)
+    with pytest.raises(ValueError, match="not a multiple"):
+        ops.int8_matmul(a, b, jnp.ones((200,)), jnp.ones((128,)))
+    with pytest.raises(ValueError, match="not a multiple"):
+        ops.quantize_rows(jnp.zeros((300, 128)))

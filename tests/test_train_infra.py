@@ -94,8 +94,8 @@ def test_checkpoint_async_matches_sync(tmp_path):
 def test_restore_with_resharding_identity(tmp_path):
     """Mesh-independent restore: device_put with explicit (single-device)
     sharding reproduces the same values — the elastic-restart path."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("data",), (AxisType.Auto,))
     tree = {"w": jnp.arange(8.0).reshape(2, 4)}
     ckpt.save(str(tmp_path), 1, tree)
     sh = {"w": NamedSharding(mesh, P(None, None))}

@@ -31,10 +31,6 @@ import os
 import sys
 import time
 
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512").strip()
-os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
-
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
@@ -222,6 +218,7 @@ def roofline_main(a):
     from repro.launch import dryrun, mesh as mesh_mod
     from repro.models import lm as lm_mod
 
+    dryrun.force_host_devices()
     cfg = get_config(a.arch)
     if a.set:
         cfg = dataclasses.replace(cfg, **dict(parse_override(s) for s in a.set))

@@ -22,10 +22,6 @@ import os
 import sys
 import time
 
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512").strip()
-os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
-
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # the paper's precision sub-lattice: None = config default per field
