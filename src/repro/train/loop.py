@@ -3,9 +3,18 @@
 Step functions are pure and jit-donated; the outer loop owns checkpointing
 (atomic + async), resume-from-latest, loader-state capture, a preemption
 hook, and a per-step heartbeat for straggler monitoring (DESIGN.md §7).
+
+Each XR step records host spans in ``repro.spans.RECORDER``, all with the
+step number: ``train.step`` around the iteration, and inside it
+``train.next`` (the loader), ``train.put`` (the batch's copy to the device),
+``train.dispatch`` (the jitted step's call), ``train.fetch`` (the wait for
+the loss) and ``train.hooks`` (heartbeat, straggler check, logging,
+checkpoints, preemption). The counter ``train.step_traces`` counts traces
+of the step. The spans wrap the loop as it is: they add no synchronisation.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import signal
 import time
@@ -15,10 +24,12 @@ from typing import Callable, Dict, Iterator, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.train import checkpoint as ckpt_mod
 from repro.train import optim
 
 f32 = jnp.float32
+STEP_TRACES = "train.step_traces"
 
 
 @dataclass
@@ -44,6 +55,8 @@ def make_xr_step(cfg, loss_fn, lr_fn, max_grad_norm: float = 1.0):
     from repro.models import xr
 
     def step_fn(params, state, opt_state, batch, step):
+        spans.RECORDER.count(STEP_TRACES)     # once per trace
+
         def loss_of(p):
             outs, new_state = xr.forward(cfg, p, state, batch["image"],
                                          train=True)
@@ -97,44 +110,63 @@ def run_xr_training(cfg, params, state, batches: Iterator, *,
     with contextlib.suppress(ValueError):      # non-main thread
         signal.signal(signal.SIGTERM, lambda *_: preempted.append(True))
 
-    losses, times, writer = [], [], None
+    losses, recent, writer = [], collections.deque(maxlen=256), None
+    rec = spans.RECORDER
     for step in range(start, steps):
-        t0 = time.monotonic()
-        batch, loader_idx = next(batches)
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        params, state, opt_state, metrics = step_fn(
-            params, state, opt_state, batch, jnp.asarray(step))
-        loss = float(metrics["loss"])
-        losses.append(loss)
-        dt = time.monotonic() - t0
-        times.append(dt)
-        if hooks.heartbeat:
-            hooks.heartbeat(step, dt)
-        med = sorted(times)[len(times) // 2]
-        if dt > hooks.straggler_threshold * med and len(times) > 10:
-            print(f"[straggler] step {step} took {dt:.2f}s (median {med:.2f}s)")
-        if hooks.log_every and step % hooks.log_every == 0:
-            print(f"step {step:5d} loss {loss:.4f} "
-                  + " ".join(f"{k}={float(v):.4f}" for k, v in metrics.items()
-                             if k != "loss"))
-        if ckpt_dir and (step + 1) % ckpt_every == 0:
-            writer = ckpt_mod.save_async(
-                ckpt_dir, step + 1,
-                {"params": params, "state": state, "opt": opt_state},
-                extra={"loader_idx": loader_idx})
-        if preempted:
-            if hooks.on_preempt:
-                hooks.on_preempt(step)
-            if ckpt_dir:
-                ckpt_mod.save(ckpt_dir, step + 1,
-                              {"params": params, "state": state,
-                               "opt": opt_state},
-                              extra={"loader_idx": loader_idx})
-            break
+        with rec.span("train.step", step) as whole:
+            t0 = time.monotonic()
+            traces = rec.counters().get(STEP_TRACES, 0)
+            with rec.span("train.next", step) as s_next:
+                batch, loader_idx = next(batches)
+            with rec.span("train.put", step) as s_put:
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            with rec.span("train.dispatch", step) as s_dispatch:
+                params, state, opt_state, metrics = step_fn(
+                    params, state, opt_state, batch, jnp.asarray(step))
+            with rec.span("train.fetch", step) as s_fetch:
+                loss = float(metrics["loss"])
+            losses.append(loss)
+            dt = time.monotonic() - t0
+            with rec.span("train.hooks", step):
+                if hooks.heartbeat:
+                    hooks.heartbeat(step, dt)
+                if len(recent) >= 10:
+                    med = sorted(recent)[len(recent) // 2]
+                    if dt > hooks.straggler_threshold * med:
+                        _warn_straggler(step, dt, med,
+                                        (s_next, s_put, s_dispatch, s_fetch),
+                                        rec.counters().get(STEP_TRACES, 0) > traces)
+                if hooks.log_every and step % hooks.log_every == 0:
+                    print(f"step {step:5d} loss {loss:.4f} "
+                          + " ".join(f"{k}={float(v):.4f}"
+                                     for k, v in metrics.items() if k != "loss"))
+                if ckpt_dir and (step + 1) % ckpt_every == 0:
+                    writer = ckpt_mod.save_async(
+                        ckpt_dir, step + 1,
+                        {"params": params, "state": state, "opt": opt_state},
+                        extra={"loader_idx": loader_idx})
+                if preempted:
+                    if hooks.on_preempt:
+                        hooks.on_preempt(step)
+                    if ckpt_dir:
+                        ckpt_mod.save(ckpt_dir, step + 1,
+                                      {"params": params, "state": state,
+                                       "opt": opt_state},
+                                      extra={"loader_idx": loader_idx})
+                    break
+        recent.append(whole.seconds)
     if writer is not None:
         writer.join()
     return TrainResult(params, opt_state, {"state": state}, losses,
                        step + 1 if steps else 0)
+
+
+def _warn_straggler(step: int, dt: float, med: float, children, retraced: bool):
+    """Name the slowest part of a slow step, and whether it retraced."""
+    slow = max(children, key=lambda s: s.seconds)
+    print(f"[straggler] step {step} took {dt:.2f}s (median {med:.2f}s): "
+          f"{slow.name} {slow.seconds:.2f}s"
+          + ("; the step retraced" if retraced else ""))
 
 
 def _skip_to(batches: Iterator, loader_idx: int) -> Iterator:
