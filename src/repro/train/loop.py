@@ -4,13 +4,25 @@ Step functions are pure and jit-donated; the outer loop owns checkpointing
 (atomic + async), resume-from-latest, loader-state capture, a preemption
 hook, and a per-step heartbeat for straggler monitoring (DESIGN.md §7).
 
+The XR loop keeps one batch of device-side prefetch: once step k is
+dispatched, the loader is called for step k+1 and that batch's copy to the
+device is issued, while step k runs on the device; only then does the loop
+wait for step k's loss. Each step still draws one batch and makes one copy,
+in the loader's order; a batch prefetched before a preemption is dropped,
+and a loader that runs dry ends the loop at the step that finds no batch.
+
 Each XR step records host spans in ``repro.spans.RECORDER``, all with the
 step number: ``train.step`` around the iteration, and inside it
-``train.next`` (the loader), ``train.put`` (the batch's copy to the device),
 ``train.dispatch`` (the jitted step's call), ``train.fetch`` (the wait for
 the loss) and ``train.hooks`` (heartbeat, straggler check, logging,
-checkpoints, preemption). The counter ``train.step_traces`` counts traces
-of the step. The spans wrap the loop as it is: they add no synchronisation.
+checkpoints, preemption). ``train.next`` (the loader) and ``train.put``
+(the batch's copy to the device) carry the number of the step that consumes
+the batch: they open inside the previous ``train.step``, between its
+dispatch and its fetch, and inside its own only for the first step. The
+counter ``train.step_traces`` counts traces of the step, and
+``train.prefetched`` the batches put on the device before the previous
+step's loss was fetched: steps - 1 in a run that reaches ``steps``. The
+spans wrap the loop as it is: they add no synchronisation.
 """
 from __future__ import annotations
 
@@ -30,6 +42,7 @@ from repro.train import optim
 
 f32 = jnp.float32
 STEP_TRACES = "train.step_traces"
+PREFETCHED = "train.prefetched"
 
 
 @dataclass
@@ -112,17 +125,31 @@ def run_xr_training(cfg, params, state, batches: Iterator, *,
 
     losses, recent, writer = [], collections.deque(maxlen=256), None
     rec = spans.RECORDER
+    ahead = None        # the next step's batch, or the loader's StopIteration
     for step in range(start, steps):
         with rec.span("train.step", step) as whole:
             t0 = time.monotonic()
             traces = rec.counters().get(STEP_TRACES, 0)
-            with rec.span("train.next", step) as s_next:
-                batch, loader_idx = next(batches)
-            with rec.span("train.put", step) as s_put:
-                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            if isinstance(ahead, StopIteration):
+                raise ahead
+            children = ()
+            if ahead is None:
+                ahead = _load(rec, batches, step)
+                children = ahead[2]
+            batch, loader_idx, _ = ahead
             with rec.span("train.dispatch", step) as s_dispatch:
                 params, state, opt_state, metrics = step_fn(
                     params, state, opt_state, batch, jnp.asarray(step))
+            children += (s_dispatch,)
+            if step + 1 < steps:
+                # the next batch's copy runs on the device beside this step
+                try:
+                    ahead = _load(rec, batches, step + 1)
+                except StopIteration as e:
+                    ahead = e
+                else:
+                    rec.count(PREFETCHED)
+                    children += ahead[2]
             with rec.span("train.fetch", step) as s_fetch:
                 loss = float(metrics["loss"])
             losses.append(loss)
@@ -133,8 +160,7 @@ def run_xr_training(cfg, params, state, batches: Iterator, *,
                 if len(recent) >= 10:
                     med = sorted(recent)[len(recent) // 2]
                     if dt > hooks.straggler_threshold * med:
-                        _warn_straggler(step, dt, med,
-                                        (s_next, s_put, s_dispatch, s_fetch),
+                        _warn_straggler(step, dt, med, (*children, s_fetch),
                                         rec.counters().get(STEP_TRACES, 0) > traces)
                 if hooks.log_every and step % hooks.log_every == 0:
                     print(f"step {step:5d} loss {loss:.4f} "
@@ -161,11 +187,23 @@ def run_xr_training(cfg, params, state, batches: Iterator, *,
                        step + 1 if steps else 0)
 
 
+def _load(rec, batches: Iterator, step: int):
+    """The loader's next batch, put on the device for ``step``: the batch,
+    its loader index and the two spans."""
+    with rec.span("train.next", step) as s_next:
+        batch, loader_idx = next(batches)
+    with rec.span("train.put", step) as s_put:
+        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+    return batch, loader_idx, (s_next, s_put)
+
+
 def _warn_straggler(step: int, dt: float, med: float, children, retraced: bool):
-    """Name the slowest part of a slow step, and whether it retraced."""
+    """Name the slowest part of a slow step, the step its batch is for where
+    that part prefetched the next one, and whether the step retraced."""
     slow = max(children, key=lambda s: s.seconds)
     print(f"[straggler] step {step} took {dt:.2f}s (median {med:.2f}s): "
           f"{slow.name} {slow.seconds:.2f}s"
+          + (f" (batch for step {slow.step})" if slow.step != step else "")
           + ("; the step retraced" if retraced else ""))
 
 

@@ -15,10 +15,6 @@ from repro.models import xr
 from repro.models.params import materialize
 from repro.train import loop
 
-CHILDREN = ["train.next", "train.put", "train.dispatch", "train.fetch",
-            "train.hooks"]
-
-
 def test_recorder_keeps_order_and_ids():
     rec = spans.Recorder()
     lo = time.time_ns()
@@ -100,14 +96,21 @@ def _train(batches, steps, heartbeat=None):
 
 
 def test_training_records_each_step_and_its_phases_in_order():
+    """Each iteration dispatches its step, then loads the next step's batch
+    (``train.next``, ``train.put`` with that step's number) before it
+    fetches its own loss; only the first loads its own batch."""
     cfg = get_smoke("detnet")
     evs = _train(synthetic.fphab_batches(2, cfg.input_hw, cfg.in_channels), 6)
     steps = [ev for ev in evs if ev[2] == "train.step"]
     assert [k for *_, k in steps] == list(range(6))
+    load = ["train.next", "train.put"]
     for s, e, _, k in steps:
         inside = [ev for ev in evs if ev[2] != "train.step" and s <= ev[0] <= e]
-        assert [n for _, _, n, _ in inside] == CHILDREN
-        assert {i for *_, i in inside} == {k}
+        own = [(n, k) for n in load] if k == 0 else []
+        ahead = [(n, k + 1) for n in load] if k < 5 else []
+        assert [(n, i) for _, _, n, i in inside] == (
+            own + [("train.dispatch", k)] + ahead
+            + [("train.fetch", k), ("train.hooks", k)])
         assert all(s <= a <= b <= e for a, b, _, _ in inside)
         assert all(b1 <= a2 for (_, b1, _, _), (a2, _, _, _)
                    in zip(inside, inside[1:]))
@@ -133,6 +136,9 @@ def test_step_traces_counts_a_retrace_and_the_warning_names_it(capsys):
 
 
 def test_straggler_warning_names_the_slow_loader(capsys):
+    """The loader sleeps while it makes the batch of step 12, which step 11
+    prefetches: step 11 is the straggler, and the warning names the loader
+    and the step the batch is for."""
     cfg = get_smoke("detnet")
 
     def slow_once():
@@ -144,6 +150,7 @@ def test_straggler_warning_names_the_slow_loader(capsys):
 
     _train(slow_once(), 14)
     warned = [ln for ln in capsys.readouterr().out.splitlines()
-              if ln.startswith("[straggler] step 12 ")]
+              if ln.startswith("[straggler] step 11 ")]
     assert len(warned) == 1
-    assert "train.next" in warned[0] and "retraced" not in warned[0]
+    assert "train.next" in warned[0] and "(batch for step 12)" in warned[0]
+    assert "retraced" not in warned[0]
