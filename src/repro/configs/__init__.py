@@ -1,7 +1,8 @@
 """Architecture registry: ``get_config(name)`` / ``get_smoke(name)``.
 
 LM archs are the 10 assigned architectures; XR archs are the paper's own
-workloads. ``--arch <id>`` anywhere in the launchers resolves through here.
+workloads; training-only archs (Kimi-VL) run through the LM trainer alone.
+``--arch <id>`` anywhere in the launchers resolves through here.
 """
 from __future__ import annotations
 
@@ -22,13 +23,19 @@ _MODULES: Dict[str, str] = {
     "mamba2-1.3b": "mamba2_1p3b",
     "jamba-1.5-large-398b": "jamba_1p5_large_398b",
     "whisper-small": "whisper_small",
+    # --- training-only LM-family configurations ---
+    "kimi-vl-a3b": "kimi_vl_a3b",
     # --- paper XR workloads ---
     "detnet": "detnet",
     "edsnet": "edsnet",
 }
 
-LM_ARCHS: List[str] = [k for k, v in _MODULES.items() if v not in ("detnet", "edsnet")]
 XR_ARCHS: List[str] = ["detnet", "edsnet"]
+# Trained through ``train.loop.run_lm_training``; no decode path, so not
+# among the dry-run's train/prefill/decode cells.
+TRAIN_ONLY_ARCHS: List[str] = ["kimi-vl-a3b"]
+LM_ARCHS: List[str] = [k for k in _MODULES
+                       if k not in XR_ARCHS and k not in TRAIN_ONLY_ARCHS]
 
 # Assigned input-shape sets (LM family): name -> (seq_len, global_batch, kind)
 SHAPES = {
@@ -62,6 +69,8 @@ def cell_is_runnable(arch: str, shape: str) -> tuple[bool, str]:
     cfg = get_config(arch)
     if not isinstance(cfg, ModelConfig):
         return False, "XR arch: evaluated on the edge-DSE plane, not the LM dry-run"
+    if arch in TRAIN_ONLY_ARCHS:
+        return False, "training-only arch: no decode path"
     if shape == "long_500k" and not cfg.sub_quadratic:
         return False, "long_500k skipped: pure full/windowed attention (see DESIGN §4)"
     return True, ""

@@ -46,6 +46,26 @@ class ModelConfig:
     moe_period: int = 1             # MoE replaces dense MLP every `period` layers
     moe_offset: int = 0             # layer i is MoE iff i % period == offset
     capacity_factor: float = 1.25
+    # DeepSeek-V3 routing (``router_scoring="sigmoid"``): sigmoid scores, a
+    # correction bias (router state, not a parameter) in the choice only,
+    # the chosen scores normalised and scaled, dropless grouped experts.
+    router_scoring: str = "softmax"      # softmax (capacity) | sigmoid
+    routed_scaling_factor: float = 1.0
+    bias_update_rate: float = 0.0        # gamma of b_i += gamma*sign(mean - load_i)
+    seq_aux_weight: float = 0.0          # alpha of the sequence-wise balance loss
+    moe_d_ff: int = 0                    # expert width (0: d_ff)
+    num_shared_experts: int = 0          # one SwiGLU of moe_d_ff * n wide
+    # Expert share: this chip holds experts [expert_offset, expert_offset +
+    # experts_held) of the router's num_experts (0: all of them).
+    experts_held: int = 0
+    expert_offset: int = 0
+    first_dense_layers: int = 0          # dense layers before the MoE stack
+
+    # --- multi-head latent attention (DeepSeek-V2/V3; q_lora_rank null) ----
+    kv_lora_rank: int = 0                # 0 = GQA attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- SSM (Mamba-2 / SSD) -------------------------------------------------
     ssm_state: int = 0              # d_state; 0 = no SSM layers
@@ -61,8 +81,19 @@ class ModelConfig:
     cross_attention: bool = False
     num_encoder_frames: int = 0     # stub conv-frontend output length
 
-    # --- VLM stub (phi-3-vision) ----------------------------------------------
-    num_image_tokens: int = 0       # precomputed patch embeddings merged in
+    # --- VLM (phi-3-vision stub; Kimi-VL MoonViT tower) ------------------------
+    num_image_tokens: int = 0       # image embeddings at positions 0..n-1
+    # Vision tower (0 layers: image embeddings come precomputed, the stub).
+    vision_layers: int = 0
+    vision_d_model: int = 0
+    vision_heads: int = 0
+    vision_d_ff: int = 0
+    vision_patch: int = 14
+    vision_pos_grid: int = 64           # learned position table, resized
+    vision_rope_theta: float = 10_000.0
+    vision_merge: int = 2               # k x k patches -> one LM token
+    vision_norm_eps: float = 1e-5
+    image_hw: int = 0                   # square frames, pixels a side
 
     # --- misc -----------------------------------------------------------------
     act: str = "silu"               # silu (SwiGLU) | gelu (GeGLU)
@@ -72,6 +103,8 @@ class ModelConfig:
     tie_embeddings: bool = False
     scale_embedding: bool = False   # gemma2: x *= sqrt(d_model) after lookup
     dtype: str = "bfloat16"
+    param_dtype: str = "bfloat16"   # parameters (and their AdamW moments: f32)
+    compute_dtype: str = "bfloat16"  # matmul inputs where param_dtype differs
     # Scaled-down flag (smoke tests); full configs are dry-run-only.
     is_smoke: bool = False
     # Whether a 500k-token decode is admissible (sub-quadratic memory growth).
@@ -105,6 +138,22 @@ class ModelConfig:
         return self.num_kv_heads * self.head_dim
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -121,6 +170,8 @@ class ModelConfig:
         return i % self.attn_period == self.attn_offset
 
     def is_moe_layer(self, i: int) -> bool:
+        """Layer ``i`` of the scanned stack: the leading dense layers
+        (``first_dense_layers``) come before it and are not counted."""
         if self.num_experts == 0:
             return False
         return i % self.moe_period == self.moe_offset
@@ -135,6 +186,8 @@ class ModelConfig:
 
     def param_count(self) -> int:
         """Analytic parameter count (used for 6ND roofline cross-check)."""
+        if self.mla or self.vision_layers:
+            raise NotImplementedError("count params.count(lm.param_defs(cfg))")
         V, D, L = self.vocab_size, self.d_model, self.num_layers
         total = V * D                        # input embedding
         if not self.tie_embeddings:
@@ -150,6 +203,8 @@ class ModelConfig:
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: only routed experts)."""
+        if self.mla or self.vision_layers:
+            raise NotImplementedError("count params.count(lm.param_defs(cfg))")
         V, D, L = self.vocab_size, self.d_model, self.num_layers
         total = V * D + (0 if self.tie_embeddings else V * D)
         for i in range(L):

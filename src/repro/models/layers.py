@@ -310,6 +310,23 @@ def mlp(cfg: ModelConfig, p: Dict, x: jax.Array) -> jax.Array:
 def moe_param_defs(cfg: ModelConfig, layer_dim: Tuple[int, ...] = ()) -> Dict:
     D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts
     ax = tuple(["layer"] * len(layer_dim))
+    if cfg.router_scoring == "sigmoid":
+        # the router over every expert; the weights of the held experts only
+        G, F = cfg.held_experts, cfg.expert_ff
+        d = {
+            "norm": ParamDef(layer_dim + (D,), ax + ("embed",), "zeros"),
+            "router": ParamDef(layer_dim + (D, E), ax + ("fsdp", None), "scaled"),
+            "we_gate": ParamDef(layer_dim + (G, D, F),
+                                ax + ("expert", "fsdp", "tensor"), "scaled"),
+            "we_up": ParamDef(layer_dim + (G, D, F),
+                              ax + ("expert", "fsdp", "tensor"), "scaled"),
+            "we_down": ParamDef(layer_dim + (G, F, D),
+                                ax + ("expert", "tensor", "fsdp"), "scaled"),
+        }
+        if cfg.num_shared_experts:
+            d["shared"] = swiglu_param_defs(D, F * cfg.num_shared_experts,
+                                            layer_dim)
+        return d
     return {
         "norm": ParamDef(layer_dim + (D,), ax + ("embed",), "zeros"),
         "router": ParamDef(layer_dim + (D, E), ax + ("fsdp", None), "scaled"),
@@ -319,15 +336,22 @@ def moe_param_defs(cfg: ModelConfig, layer_dim: Tuple[int, ...] = ()) -> Dict:
     }
 
 
-def moe(cfg: ModelConfig, p: Dict, x: jax.Array
-        ) -> Tuple[jax.Array, jax.Array]:
-    """Top-k token-choice MoE with capacity-bounded index dispatch.
+def moe(cfg: ModelConfig, p: Dict, x: jax.Array,
+        bias: Optional[jax.Array] = None
+        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Token-choice MoE. Returns (output, balance loss, load): ``load`` is
+    the token-slots routed to each of the router's experts, (E,) float32.
 
-    Avoids the (T, E, C) GShard one-hot dispatch tensor: tokens are gathered
-    into an (E, C) index buffer (scatter with OOB drop), run through batched
-    expert FFNs, and scatter-added back. FLOPs ~= topk * cf * T * 6DF.
-    Returns (output, load_balance_aux_loss).
+    ``router_scoring="sigmoid"`` takes the DeepSeek-V3 path (``moe_v3``,
+    with the router state's correction ``bias``). Otherwise: softmax top-k
+    with capacity-bounded index dispatch. That path avoids the (T, E, C)
+    GShard one-hot dispatch tensor: tokens are gathered into an (E, C)
+    index buffer (scatter with OOB drop), run through batched expert FFNs,
+    and scatter-added back. FLOPs ~= topk * cf * T * 6DF; slots over an
+    expert's capacity are dropped.
     """
+    if cfg.router_scoring == "sigmoid":
+        return moe_v3(cfg, p, x, bias)
     B, S, D = x.shape
     E, topk = cfg.num_experts, cfg.experts_per_token
     T = B * S
@@ -380,8 +404,201 @@ def moe(cfg: ModelConfig, p: Dict, x: jax.Array
     contrib = jnp.where(valid[:, None], contrib, 0)
     y = shard(contrib.reshape(T, topk, D), "batch", None, "embed")
     y = jnp.sum(y, axis=1).reshape(B, S, D)
-    return shard(y, "batch", "seq", "embed"), aux
+    return shard(y, "batch", "seq", "embed"), aux, f_e * T
 
+
+def moe_v3(cfg: ModelConfig, p: Dict, x: jax.Array,
+           bias: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """DeepSeek-V3 MoE over this chip's expert share.
+
+    The router scores all ``num_experts`` with a sigmoid; the correction
+    ``bias`` (router state) enters the top-k choice only; the chosen scores
+    are normalised to sum 1 and scaled by ``routed_scaling_factor``. This
+    chip computes the experts it holds (``routed_experts``) for the slots
+    routed to them, and the shared experts for every token. Returns
+    (output, sequence-wise balance loss, load over all experts).
+    """
+    B, S, D = x.shape
+    E, K = cfg.num_experts, cfg.experts_per_token
+    xf = x.reshape(B * S, D)
+    with jax.named_scope("moe.router"):
+        scores = jax.nn.sigmoid(jnp.matmul(
+            xf.astype(f32), p["router"].astype(f32),
+            precision=lax.Precision.HIGHEST))                  # (T, E)
+        choice = scores if bias is None else scores + bias.astype(f32)
+        _, eidx = lax.top_k(choice, K)                         # (T, K)
+        w = jnp.take_along_axis(scores, eidx, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w * cfg.routed_scaling_factor
+        load = jnp.sum(jax.nn.one_hot(eidx, E, dtype=f32), axis=(0, 1))
+        aux = seq_aux_loss(scores.reshape(B, S, E), eidx.reshape(B, S, K), E)
+    with jax.named_scope("moe.routed"):
+        y = routed_experts(cfg, p, xf, eidx, w)
+    if cfg.num_shared_experts:
+        with jax.named_scope("moe.shared"):
+            y = y + shared_experts(cfg, p, xf)
+    return y.reshape(B, S, D).astype(x.dtype), aux, load
+
+
+def seq_aux_loss(scores: jax.Array, eidx: jax.Array, E: int) -> jax.Array:
+    """DeepSeek-V3's sequence-wise balance loss (arXiv:2412.19437, eqs.
+    17-20), without its alpha: per sequence, sum_i f_i P_i with f_i = E/(K S)
+    times the slots routed to expert i and P_i the mean over the sequence of
+    the scores normalised over experts; the mean over sequences."""
+    _, S, K = eidx.shape
+    f = jnp.sum(jax.nn.one_hot(eidx, E, dtype=f32), axis=(1, 2)) * (E / (K * S))
+    P = jnp.mean(scores / jnp.sum(scores, axis=-1, keepdims=True), axis=1)
+    return jnp.mean(jnp.sum(lax.stop_gradient(f) * P, axis=-1))
+
+
+def routed_experts(cfg: ModelConfig, p: Dict, xf: jax.Array, eidx: jax.Array,
+                   w: jax.Array) -> jax.Array:
+    """The held experts' part of the routed output, dropless.
+
+    Every token-slot (T*K of them, the worst case) is sorted by its expert:
+    the slots of held experts first, grouped in expert order, then the rest.
+    Grouped matmuls (``lax.ragged_dot``) run each group through its expert,
+    so no slot is dropped under any imbalance. Each slot's output, times
+    its weight, is added to its token.
+
+    The rows past the groups are left undefined by the TPU's grouped
+    matmul, in its output and in its gradient for the rows, so each grouped
+    matmul's rows past the groups are zeroed on the way in (which zeroes
+    that gradient) and on the way out.
+    """
+    T, D = xf.shape
+    K = eidx.shape[1]
+    G, lo = cfg.held_experts, cfg.expert_offset
+    cd = jnp.dtype(cfg.compute_dtype)
+    local = eidx.reshape(-1) - lo
+    held = (local >= 0) & (local < G)
+    key = jnp.where(held, local, G)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, G, dtype=jnp.int32), axis=0)
+    live = (jnp.arange(T * K) < jnp.sum(sizes))[:, None]
+    xs = xf[order // K].astype(cd)                             # (T*K, D)
+
+    def gmm(a, wt):
+        a = jnp.where(live, a, jnp.zeros((), a.dtype))
+        y = lax.ragged_dot(a, wt.astype(cd), sizes, preferred_element_type=cd)
+        return jnp.where(live, y, jnp.zeros((), cd))
+
+    h = jax.nn.silu(gmm(xs, p["we_gate"]).astype(f32)) * gmm(xs, p["we_up"])
+    ys = gmm(h.astype(cd), p["we_down"])                       # (T*K, D)
+    # back to token order: slot j of token t sits at row inv[t*K + j]
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    wt = jnp.where(held, w.reshape(-1), 0.0).reshape(T, K)
+    return jnp.einsum("tkd,tk->td", ys[inv].reshape(T, K, D).astype(f32), wt)
+
+
+def shared_experts(cfg: ModelConfig, p: Dict, xf: jax.Array) -> jax.Array:
+    """The shared experts, one SwiGLU of ``num_shared_experts`` times the
+    expert width, on every token."""
+    return swiglu(cfg, p["shared"], xf)
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3 block pieces: mixed-precision matmuls, SwiGLU, MLA
+# ---------------------------------------------------------------------------
+
+def mm(cfg: ModelConfig, x: jax.Array, w: jax.Array) -> jax.Array:
+    """``x @ w`` with both operands in ``compute_dtype``, accumulated and
+    returned in float32."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    return jnp.matmul(x.astype(cd), w.astype(cd), preferred_element_type=f32)
+
+
+def swiglu(cfg: ModelConfig, p: Dict, x: jax.Array) -> jax.Array:
+    h = jax.nn.silu(mm(cfg, x, p["wi_gate"])) * mm(cfg, x, p["wi_up"])
+    return mm(cfg, h, p["wo"])
+
+
+def swiglu_param_defs(D: int, F: int, layer_dim: Tuple[int, ...] = ()) -> Dict:
+    ax = tuple(["layer"] * len(layer_dim))
+    return {
+        "wi_gate": ParamDef(layer_dim + (D, F), ax + ("fsdp", "tensor"), "scaled"),
+        "wi_up": ParamDef(layer_dim + (D, F), ax + ("fsdp", "tensor"), "scaled"),
+        "wo": ParamDef(layer_dim + (F, D), ax + ("tensor", "fsdp"), "scaled"),
+    }
+
+
+def mla_param_defs(cfg: ModelConfig, layer_dim: Tuple[int, ...] = ()) -> Dict:
+    D, H, R = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    ax = tuple(["layer"] * len(layer_dim))
+    return {
+        "norm": ParamDef(layer_dim + (D,), ax + ("embed",), "zeros"),
+        "wq": ParamDef(layer_dim + (D, H * cfg.qk_head_dim),
+                       ax + ("fsdp", "tensor"), "scaled"),
+        "wkv_a": ParamDef(layer_dim + (D, R + cfg.qk_rope_head_dim),
+                          ax + ("fsdp", None), "scaled"),
+        "kv_norm": ParamDef(layer_dim + (R,), ax + (None,), "zeros"),
+        "wkv_b": ParamDef(layer_dim + (R, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                          ax + (None, "tensor"), "scaled"),
+        "wo": ParamDef(layer_dim + (H * cfg.v_head_dim, D),
+                       ax + ("tensor", "fsdp"), "scaled"),
+    }
+
+
+def mla_rope(cfg: ModelConfig, q_pe: jax.Array, k_pe: jax.Array,
+             positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """RoPE on the decoupled rope parts: q_pe (B,S,H,r), k_pe (B,S,1,r)."""
+    return (apply_rope(q_pe, positions, cfg.rope_theta),
+            apply_rope(k_pe, positions, cfg.rope_theta))
+
+
+def mla(cfg: ModelConfig, p: Dict, x: jax.Array, positions: jax.Array,
+        q_block: int = 256) -> jax.Array:
+    """Multi-head latent attention, training form (DeepSeek-V2/V3 with
+    ``q_lora_rank`` null): q = x W_q split into nope and rope parts;
+    [c_kv, k_pe] = x W_kva; c_kv -> RMSNorm -> W_kvb -> k_nope, v; RoPE on
+    q_pe and k_pe, k_pe shared by the heads; scale 1/sqrt(nope + rope);
+    causal."""
+    B, S, _ = x.shape
+    H, dn, dr, dv = (cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.v_head_dim)
+    R = cfg.kv_lora_rank
+    cd = jnp.dtype(cfg.compute_dtype)
+    with jax.named_scope("mla"):
+        q = mm(cfg, x, p["wq"]).reshape(B, S, H, dn + dr)
+        kv_a = mm(cfg, x, p["wkv_a"])
+        c_kv = rmsnorm(kv_a[..., :R], p["kv_norm"], cfg.norm_eps)
+        kv = mm(cfg, c_kv, p["wkv_b"]).reshape(B, S, H, dn + dv)
+        q_pe, k_pe = mla_rope(cfg, q[..., dn:], kv_a[..., None, R:], positions)
+        q = jnp.concatenate([q[..., :dn], q_pe], axis=-1).astype(cd)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+            k_pe, (B, S, H, dr))], axis=-1).astype(cd)
+        out = blocked_attention(q, k, kv[..., dn:].astype(cd),
+                                1.0 / math.sqrt(dn + dr), True, q_block)
+        return mm(cfg, out.reshape(B, S, H * dv), p["wo"])
+
+
+def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
+                      causal: bool, q_block: int) -> jax.Array:
+    """Softmax attention by query blocks: q, k (B,S,H,dk), v (B,S,H,dv) ->
+    (B,S,H,dv) float32. Scores and softmax are float32. A causal block
+    attends to the key prefix it can reach. Each block is rematerialised,
+    so the backward pass holds one block's scores at a time."""
+    B, S, H, _ = q.shape
+    qb = min(q_block, S)
+    if S % qb:
+        raise ValueError(f"sequence {S} is not a multiple of the block {qb}")
+
+    def block(qi, ki, vi, start):
+        s = jnp.einsum("bqhd,bkhd->bhqk", qi, ki,
+                       preferred_element_type=f32) * scale
+        if causal:
+            qpos = start + jnp.arange(qi.shape[1])[:, None]
+            s = jnp.where(jnp.arange(ki.shape[1])[None, :] <= qpos, s, -1e30)
+        pr = jax.nn.softmax(s, axis=-1).astype(vi.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", pr, vi, preferred_element_type=f32)
+
+    outs = []
+    for start in range(0, S, qb):
+        end = start + qb if causal else S
+        fn = jax.checkpoint(block, static_argnums=(3,))
+        outs.append(fn(q[:, start:start + qb], k[:, :end], v[:, :end], start))
+    return jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
 
 # ---------------------------------------------------------------------------
 # Mamba-2 (SSD)

@@ -1,5 +1,8 @@
 """Unified LM zoo: one scan-over-layers transformer covering all 10 assigned
-architectures (dense / MoE / SSM / hybrid / enc-dec / VLM-stub).
+architectures (dense / MoE / SSM / hybrid / enc-dec / VLM-stub) and the
+DeepSeek-V3 block (MLA, sigmoid-routed MoE with shared experts over an
+expert share, leading dense layers) under Kimi-VL's MoonViT tower
+(``models/vision.py``).
 
 Heterogeneous stacks (gemma2 local/global alternation, jamba 1:7 attn:ssm +
 alternating MoE) are handled with a *period block*: the layer pattern repeats
@@ -9,13 +12,16 @@ is executed with ``lax.scan`` over repeats (static python loop over the
 sublayers inside). This keeps HLO size O(1) in depth — required both for the
 1-core-CPU compile budget here and for real compile times at 1000+ nodes.
 
-Three public entry points (all pure functions):
+Public entry points (all pure functions):
   * ``param_defs(cfg)``                          — ParamDef pytree
   * ``forward(cfg, params, tokens, ...)``        — train / prefill logits
+  * ``loss_and_load(cfg, params, batch, bias)``  — training loss and MoE load
+  * ``init_router_state`` / ``update_router_state`` — the router's bias rule
   * ``init_cache(cfg, batch, s_max)`` + ``decode_step(...)`` — serving
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Dict, Optional, Tuple
 
@@ -25,6 +31,7 @@ from jax import lax
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
+from repro.models import vision
 from repro.models.params import ParamDef
 from repro.sharding import shard
 
@@ -44,14 +51,19 @@ def block_period(cfg: ModelConfig) -> int:
         p = math.lcm(p, cfg.attn_period)
     if cfg.num_experts:
         p = math.lcm(p, cfg.moe_period)
-    if cfg.num_layers % p != 0:
-        raise ValueError(f"{cfg.name}: num_layers={cfg.num_layers} not a "
-                         f"multiple of layer pattern period {p}")
+    if stack_layers(cfg) % p != 0:
+        raise ValueError(f"{cfg.name}: {stack_layers(cfg)} stacked layers not "
+                         f"a multiple of layer pattern period {p}")
     return p
 
 
+def stack_layers(cfg: ModelConfig) -> int:
+    """Layers of the scanned stack: all but the leading dense ones."""
+    return cfg.num_layers - cfg.first_dense_layers
+
+
 def num_repeats(cfg: ModelConfig) -> int:
-    return cfg.num_layers // block_period(cfg)
+    return stack_layers(cfg) // block_period(cfg)
 
 
 def sublayer_kind(cfg: ModelConfig, j: int) -> Dict[str, bool]:
@@ -78,7 +90,8 @@ def _sublayer_defs(cfg: ModelConfig, j: int, R: int) -> Dict:
     ld = (R,)
     d: Dict[str, Dict] = {}
     if kind["attn"]:
-        d["attn"] = L.attn_param_defs(cfg, ld)
+        d["attn"] = (L.mla_param_defs(cfg, ld) if cfg.mla
+                     else L.attn_param_defs(cfg, ld))
         if cfg.sandwich_norm:
             d["attn"]["post_norm"] = ParamDef(ld + (cfg.d_model,),
                                               ("layer", "embed"), "zeros")
@@ -107,6 +120,13 @@ def param_defs(cfg: ModelConfig) -> Dict:
     }
     if not cfg.tie_embeddings:
         defs["head"] = ParamDef((D, V), ("fsdp", "tensor"), "scaled")
+    if cfg.first_dense_layers:
+        ld = (cfg.first_dense_layers,)
+        defs["dense"] = {"attn": L.mla_param_defs(cfg, ld),
+                         "mlp": L.mlp_param_defs(cfg, ld)}
+    if cfg.vision_layers:
+        defs["vision"] = vision.param_defs(cfg)
+        defs["projector"] = vision.projector_defs(cfg)
     if cfg.encoder_layers:
         E = cfg.encoder_layers
         enc = {
@@ -115,6 +135,9 @@ def param_defs(cfg: ModelConfig) -> Dict:
         }
         defs["encoder"] = {"layers": enc,
                            "final_norm": ParamDef((D,), ("embed",), "zeros")}
+    if cfg.param_dtype != "bfloat16":
+        defs = jax.tree.map(lambda d: dataclasses.replace(d, dtype=cfg.param_dtype),
+                            defs, is_leaf=lambda d: isinstance(d, ParamDef))
     return defs
 
 
@@ -124,11 +147,17 @@ def param_defs(cfg: ModelConfig) -> Dict:
 
 def _apply_sublayer(cfg: ModelConfig, kind: Dict, p: Dict, x: jax.Array,
                     positions: jax.Array, aux: jax.Array,
-                    enc_kv: Optional[Tuple] = None):
-    """Pre-norm residual sublayer (train / prefill form)."""
+                    enc_kv: Optional[Tuple] = None,
+                    bias: Optional[jax.Array] = None):
+    """Pre-norm residual sublayer (train / prefill form). Returns (x, aux,
+    load): ``load`` is the MoE layer's slots by expert, or None."""
+    load = None
     if kind["attn"]:
         h = L.rmsnorm(x, p["attn"]["norm"], cfg.norm_eps)
-        h = L.attention(cfg, p["attn"], h, positions, is_local=kind["local"])
+        if cfg.mla:
+            h = L.mla(cfg, p["attn"], h, positions)
+        else:
+            h = L.attention(cfg, p["attn"], h, positions, is_local=kind["local"])
         if cfg.sandwich_norm:
             h = L.rmsnorm(h, p["attn"]["post_norm"], cfg.norm_eps)
         x = x + h
@@ -140,17 +169,37 @@ def _apply_sublayer(cfg: ModelConfig, kind: Dict, p: Dict, x: jax.Array,
         x = x + L.cross_attention(cfg, p["xattn"], h, *enc_kv)
     if kind["moe"]:
         h = L.rmsnorm(x, p["moe"]["norm"], cfg.norm_eps)
-        h, a = L.moe(cfg, p["moe"], h)
+        h, a, load = L.moe(cfg, p["moe"], h, bias)
         if cfg.sandwich_norm:
             h = L.rmsnorm(h, p["moe"]["post_norm"], cfg.norm_eps)
         x, aux = x + h, aux + a
     elif kind["mlp"]:
-        h = L.rmsnorm(x, p["mlp"]["norm"], cfg.norm_eps)
-        h = L.mlp(cfg, p["mlp"], h)
-        if cfg.sandwich_norm:
-            h = L.rmsnorm(h, p["mlp"]["post_norm"], cfg.norm_eps)
-        x = x + h
-    return x, aux
+        x = _dense_mlp(cfg, p["mlp"], x)
+    return x, aux, load
+
+
+def _dense_mlp(cfg: ModelConfig, p: Dict, x: jax.Array) -> jax.Array:
+    h = L.rmsnorm(x, p["norm"], cfg.norm_eps)
+    if cfg.mla:                          # DeepSeek block: mixed precision
+        with jax.named_scope("dense_mlp"):
+            return x + L.swiglu(cfg, p, h)
+    h = L.mlp(cfg, p, h)
+    if cfg.sandwich_norm:
+        h = L.rmsnorm(h, p["post_norm"], cfg.norm_eps)
+    return x + h
+
+
+def _leading_dense(cfg: ModelConfig, params: Dict, x: jax.Array,
+                   positions: jax.Array) -> jax.Array:
+    """The dense layers before the scanned stack (MLA + SwiGLU)."""
+    def body(x, p):
+        h = L.rmsnorm(x, p["attn"]["norm"], cfg.norm_eps)
+        x = x + L.mla(cfg, p["attn"], h, positions)
+        return _dense_mlp(cfg, p["mlp"], x), None
+
+    fn = jax.checkpoint(body) if cfg.remat else body
+    x, _ = lax.scan(fn, x, params["dense"])
+    return x
 
 
 def _embed(cfg: ModelConfig, params: Dict, tokens: jax.Array,
@@ -243,10 +292,24 @@ def forward(cfg: ModelConfig, params: Dict, tokens: jax.Array, *,
             encoder_frames: Optional[jax.Array] = None,
             ) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence forward. Returns (logits fp32 (B,S,V), moe_aux_loss)."""
+    x, aux, _ = trunk(cfg, params, tokens, image_embeds=image_embeds,
+                      encoder_frames=encoder_frames)
+    return _unembed(cfg, params, x), aux / max(1, cfg.num_layers)
+
+
+def trunk(cfg: ModelConfig, params: Dict, tokens: jax.Array, *,
+          image_embeds: Optional[jax.Array] = None,
+          encoder_frames: Optional[jax.Array] = None,
+          router_bias: Optional[jax.Array] = None):
+    """Embedding and every layer, before the final norm. Returns (hidden
+    (B,S,D), summed MoE aux loss, MoE load (R, E) or None). ``router_bias``
+    (R, E) is the router state's correction bias of each MoE layer."""
     B, S = tokens.shape
     period = block_period(cfg)
     x = _embed(cfg, params, tokens, image_embeds)
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    if cfg.first_dense_layers:
+        x = _leading_dense(cfg, params, x, positions)
 
     enc_kv_stacked = None
     if cfg.encoder_layers:
@@ -254,28 +317,35 @@ def forward(cfg: ModelConfig, params: Dict, tokens: jax.Array, *,
         enc_kv_stacked = encoder_kv(cfg, params, enc_out)
 
     kinds = [sublayer_kind(cfg, j) for j in range(period)]
+    if router_bias is not None and period != 1:
+        raise ValueError("a router bias needs an MoE layer in every position")
 
     def body(carry, xs):
         x, aux = carry
-        blk_params, enc_kv = xs
+        blk_params, enc_kv, bias = xs
+        load = None
         for j in range(period):
             ekv = None
             if enc_kv is not None:
                 ekv = (enc_kv["k"][j], enc_kv["v"][j])
-            x, aux = _apply_sublayer(cfg, kinds[j], blk_params[f"blk{j}"],
-                                     x, positions, aux, ekv)
-        return (x, aux), None
+            x, aux, lj = _apply_sublayer(cfg, kinds[j], blk_params[f"blk{j}"],
+                                         x, positions, aux, ekv, bias)
+            load = lj if lj is not None else load
+        return (x, aux), load
 
     fn = jax.checkpoint(body) if cfg.remat else body
-    xs = (params["blocks"], enc_kv_stacked)
+    xs = (params["blocks"], enc_kv_stacked, router_bias)
     carry = (x, jnp.zeros((), f32))
     if cfg.scan_layers:
-        (x, aux), _ = lax.scan(fn, carry, xs)
+        (x, aux), load = lax.scan(fn, carry, xs)
     else:                                # unrolled (dry-run cost probes)
+        loads = []
         for r in range(num_repeats(cfg)):
-            carry, _ = fn(carry, jax.tree.map(lambda t, r=r: t[r], xs))
+            carry, lr = fn(carry, jax.tree.map(lambda t, r=r: t[r], xs))
+            loads.append(lr)
         x, aux = carry
-    return _unembed(cfg, params, x), aux / max(1, cfg.num_layers)
+        load = None if loads[0] is None else jnp.stack(loads)
+    return x, aux, load
 
 
 # ---------------------------------------------------------------------------
@@ -353,7 +423,7 @@ def _decode_sublayer(cfg: ModelConfig, kind: Dict, p: Dict, c: Dict,
         new_c["xk"], new_c["xv"] = c["xk"], c["xv"]
     if kind["moe"]:
         h = L.rmsnorm(x, p["moe"]["norm"], cfg.norm_eps)
-        h, _ = L.moe(cfg, p["moe"], h)
+        h, _, _ = L.moe(cfg, p["moe"], h)
         if cfg.sandwich_norm:
             h = L.rmsnorm(h, p["moe"]["post_norm"], cfg.norm_eps)
         x = x + h
@@ -417,10 +487,88 @@ def xent_loss(logits: jax.Array, labels: jax.Array,
 
 def lm_loss(cfg: ModelConfig, params: Dict, batch: Dict,
             aux_weight: float = 0.01) -> Tuple[jax.Array, Dict]:
-    logits, aux = forward(
-        cfg, params, batch["tokens"],
-        image_embeds=batch.get("image_embeds"),
-        encoder_frames=batch.get("encoder_frames"))
-    loss = xent_loss(logits, batch["labels"], batch.get("mask"))
-    total = loss + aux_weight * aux
-    return total, {"xent": loss, "moe_aux": aux}
+    total, (_, metrics) = loss_and_load(cfg, params, batch, aux_weight=aux_weight)
+    return total, metrics
+
+
+def loss_and_load(cfg: ModelConfig, params: Dict, batch: Dict,
+                  router_bias: Optional[jax.Array] = None,
+                  aux_weight: float = 0.01) -> Tuple[jax.Array, Tuple]:
+    """Training loss of one batch: (total, (MoE load (R, E) or None,
+    metrics)). A configuration with a vision tower takes ``pixels`` (B, H,
+    W, 3) and ``tokens`` and is scored on its text (``text_xent``); others
+    take ``labels`` and an optional ``mask``. With sigmoid routing the aux
+    term is the sequence-wise balance loss summed over the MoE layers,
+    times ``seq_aux_weight``."""
+    image_embeds = batch.get("image_embeds")
+    if cfg.vision_layers:
+        image_embeds = vision.image_embeds(cfg, params, batch["pixels"])
+    x, aux, load = trunk(cfg, params, batch["tokens"], image_embeds=image_embeds,
+                         encoder_frames=batch.get("encoder_frames"),
+                         router_bias=router_bias)
+    if cfg.vision_layers:
+        loss = text_xent(cfg, params, x, batch["tokens"])
+    else:
+        loss = xent_loss(_unembed(cfg, params, x), batch["labels"],
+                         batch.get("mask"))
+    if cfg.router_scoring == "sigmoid":
+        total = loss + cfg.seq_aux_weight * aux
+    else:
+        aux = aux / max(1, cfg.num_layers)
+        total = loss + aux_weight * aux
+    return total, (load, {"xent": loss, "moe_aux": aux})
+
+
+HEAD_CHUNKS = 4      # the text head's logits, by chunks of the batch
+
+
+def text_xent(cfg: ModelConfig, params: Dict, x: jax.Array,
+              tokens: jax.Array) -> jax.Array:
+    """Mean next-token cross entropy over the text after the image: the
+    hidden state at position t predicts token t + 1, from the last image
+    position on, so the image positions carry no loss. Computed by chunks
+    of sequences, each rematerialised, so one chunk's logits live at a
+    time."""
+    n = cfg.num_image_tokens
+    h, labels = x[:, n - 1:-1], tokens[:, n:]
+    step = h.shape[0] // math.gcd(HEAD_CHUNKS, h.shape[0])
+
+    def chunk(norm_w, head, hc, lc):
+        hc = L.rmsnorm(hc, norm_w, cfg.norm_eps)
+        logits = L.mm(cfg, hc, head)
+        gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
+        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - gold)
+
+    with jax.named_scope("lm_head"):
+        total = sum(jax.checkpoint(chunk)(params["final_norm"], params["head"],
+                                          h[i:i + step], labels[i:i + step])
+                    for i in range(0, h.shape[0], step))
+    return total / labels.size
+
+
+# ---------------------------------------------------------------------------
+# router state (sigmoid routing): the correction bias and the routed count
+# ---------------------------------------------------------------------------
+
+def init_router_state(cfg: ModelConfig) -> Dict:
+    """The training step's state: each MoE layer's correction bias (R, E)
+    and a running count of the token-slots routed to each held expert
+    (R, held). Empty for a model without sigmoid routing."""
+    if cfg.router_scoring != "sigmoid":
+        return {}
+    R = num_repeats(cfg)
+    return {"bias": jnp.zeros((R, cfg.num_experts), f32),
+            "routed": jnp.zeros((R, cfg.held_experts), jnp.int32)}
+
+
+def update_router_state(cfg: ModelConfig, state: Dict,
+                        load: Optional[jax.Array]) -> Dict:
+    """DeepSeek-V3's bias rule, b_i += gamma * sign(mean load - load_i), on
+    the load of every expert; the held experts' slots join the count."""
+    if not state:
+        return state
+    mean = jnp.mean(load, axis=-1, keepdims=True)
+    lo = cfg.expert_offset
+    return {"bias": state["bias"] + cfg.bias_update_rate * jnp.sign(mean - load),
+            "routed": state["routed"]
+            + load[:, lo:lo + cfg.held_experts].astype(jnp.int32)}

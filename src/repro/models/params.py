@@ -33,6 +33,18 @@ def _is_def(x) -> bool:
     return isinstance(x, ParamDef)
 
 
+STACK_AXES = ("layer", "expert")
+
+
+def fan_in(d: ParamDef) -> int:
+    """The contracted size of a weight: every axis but the last, less the
+    stacking axes (layers, experts); a vector's own length."""
+    if len(d.shape) == 1:
+        return max(1, d.shape[0])
+    return max(1, int(np.prod([n for n, ax in zip(d.shape[:-1], d.axes[:-1])
+                               if ax not in STACK_AXES])))
+
+
 def materialize(defs, key: jax.Array):
     """Initialize real parameters on the default device."""
     leaves, treedef = jax.tree.flatten(defs, is_leaf=_is_def)
@@ -48,8 +60,7 @@ def materialize(defs, key: jax.Array):
             out.append(jnp.log(jnp.arange(1, d.shape[-1] + 1, dtype=jnp.float32)
                                ).astype(dt) * jnp.ones(d.shape, dt))
         else:
-            fan_in = d.shape[0] if len(d.shape) > 1 else max(1, d.shape[-1])
-            std = (d.scale / np.sqrt(fan_in) if d.init == "scaled"
+            std = (d.scale / np.sqrt(fan_in(d)) if d.init == "scaled"
                    else 0.02 * d.scale)
             out.append((jax.random.normal(k, d.shape, jnp.float32) * std).astype(dt))
     return jax.tree.unflatten(treedef, out)

@@ -1,17 +1,22 @@
-"""Training loops: XR (paper workloads, BN-state threading) and LM.
+"""Training loops: one loop body for the XR step (paper workloads, BN-state
+threading) and the LM/VLM step (router-state threading).
 
-Step functions are pure and jit-donated; the outer loop owns checkpointing
-(atomic + async), resume-from-latest, loader-state capture, a preemption
-hook, and a per-step heartbeat for straggler monitoring (DESIGN.md §7).
+Step functions are pure and jit-donated, ``(params, state, opt_state, batch,
+step) -> (params, state, opt_state, metrics)``: ``state`` is the XR nets'
+BatchNorm statistics or an MoE router's correction bias and routed count
+(empty for other LMs). The outer loop owns checkpointing (atomic + async),
+resume-from-latest, loader-state capture, a preemption hook, and a per-step
+heartbeat for straggler monitoring (DESIGN.md §7).
+``run_xr_training`` and ``run_lm_training`` differ only in their step.
 
-The XR loop keeps one batch of device-side prefetch: once step k is
+The loop keeps one batch of device-side prefetch: once step k is
 dispatched, the loader is called for step k+1 and that batch's copy to the
 device is issued, while step k runs on the device; only then does the loop
 wait for step k's loss. Each step still draws one batch and makes one copy,
 in the loader's order; a batch prefetched before a preemption is dropped,
 and a loader that runs dry ends the loop at the step that finds no batch.
 
-Each XR step records host spans in ``repro.spans.RECORDER``, all with the
+Each step records host spans in ``repro.spans.RECORDER``, all with the
 step number: ``train.step`` around the iteration, and inside it
 ``train.dispatch`` (the jitted step's call), ``train.fetch`` (the wait for
 the loss) and ``train.hooks`` (heartbeat, straggler check, logging,
@@ -88,17 +93,31 @@ def make_xr_step(cfg, loss_fn, lr_fn, max_grad_norm: float = 1.0):
 
 
 def make_lm_step(cfg, lr_fn, max_grad_norm: float = 1.0):
+    """LM/VLM step: (params, router_state, opt, batch, step) -> ... The
+    router state (``lm.init_router_state``) takes the step's MoE load; the
+    metrics carry its running count of routed slots (``routed_slots``)."""
     from repro.models import lm
 
-    def step_fn(params, opt_state, batch, step):
-        (loss, metrics), grads = jax.value_and_grad(
-            lm.lm_loss, has_aux=True, argnums=1)(cfg, params, batch)
+    def step_fn(params, state, opt_state, batch, step):
+        spans.RECORDER.count(STEP_TRACES)     # once per trace
+        (loss, (load, metrics)), grads = jax.value_and_grad(
+            lm.loss_and_load, has_aux=True, argnums=1)(
+                cfg, params, batch, state.get("bias"))
         grads, gnorm = optim.clip_by_global_norm(grads, max_grad_norm)
         params, opt_state = optim.adamw_update(
             grads, opt_state, params, lr=lr_fn(step))
-        return params, opt_state, dict(metrics, loss=loss, grad_norm=gnorm)
+        state = lm.update_router_state(cfg, state, load)
+        metrics = dict(metrics, loss=loss, grad_norm=gnorm)
+        if "routed" in state:
+            metrics["routed_slots"] = state["routed"]
+        return params, state, opt_state, metrics
 
-    return jax.jit(step_fn, donate_argnums=(0, 1))
+    return jax.jit(step_fn, donate_argnums=(0, 1, 2))
+
+
+def _schedule(lr: float, steps: int):
+    return optim.cosine_schedule(lr, warmup=min(50, steps // 10 + 1),
+                                 total=steps)
 
 
 def run_xr_training(cfg, params, state, batches: Iterator, *,
@@ -106,10 +125,27 @@ def run_xr_training(cfg, params, state, batches: Iterator, *,
                     ckpt_dir: Optional[str] = None, ckpt_every: int = 100,
                     hooks: Optional[TrainHooks] = None,
                     resume: bool = True) -> TrainResult:
+    """Train DetNet/EDSNet; ``state`` is the BatchNorm statistics."""
+    return _train(make_xr_step(cfg, loss_fn, _schedule(lr, steps)), params,
+                  state, batches, steps=steps, ckpt_dir=ckpt_dir,
+                  ckpt_every=ckpt_every, hooks=hooks, resume=resume)
+
+
+def run_lm_training(cfg, params, state, batches: Iterator, *, steps: int,
+                    lr: float = 1e-3, ckpt_dir: Optional[str] = None,
+                    ckpt_every: int = 100, hooks: Optional[TrainHooks] = None,
+                    resume: bool = True) -> TrainResult:
+    """Train an LM or VLM; ``state`` is ``lm.init_router_state(cfg)``."""
+    return _train(make_lm_step(cfg, _schedule(lr, steps)), params, state,
+                  batches, steps=steps, ckpt_dir=ckpt_dir,
+                  ckpt_every=ckpt_every, hooks=hooks, resume=resume)
+
+
+def _train(step_fn, params, state, batches: Iterator, *, steps: int,
+           ckpt_dir: Optional[str], ckpt_every: int,
+           hooks: Optional[TrainHooks], resume: bool) -> TrainResult:
+    """The loop body both trainers share."""
     hooks = hooks if hooks is not None else TrainHooks()
-    lr_fn = optim.cosine_schedule(lr, warmup=min(50, steps // 10 + 1),
-                                  total=steps)
-    step_fn = make_xr_step(cfg, loss_fn, lr_fn)
     opt_state = optim.adamw_init(params)
     start = 0
 
@@ -165,7 +201,8 @@ def run_xr_training(cfg, params, state, batches: Iterator, *,
                 if hooks.log_every and step % hooks.log_every == 0:
                     print(f"step {step:5d} loss {loss:.4f} "
                           + " ".join(f"{k}={float(v):.4f}"
-                                     for k, v in metrics.items() if k != "loss"))
+                                     for k, v in metrics.items()
+                                     if k != "loss" and v.ndim == 0))
                 if ckpt_dir and (step + 1) % ckpt_every == 0:
                     writer = ckpt_mod.save_async(
                         ckpt_dir, step + 1,

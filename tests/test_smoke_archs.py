@@ -4,6 +4,8 @@ Every arch instantiates its REDUCED config and runs one forward + one train
 step on CPU, asserting output shapes and finiteness; decode-capable archs
 additionally run one serve step.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -96,7 +98,12 @@ def test_prefill_decode_consistency_dense():
 
 
 def test_prefill_decode_consistency_hybrid():
+    # capacity for every slot: the prefill's 8 tokens may all pick one
+    # expert, which one decoded token never overflows (and the dropped
+    # slots, not the SSD forms, would then be what the check sees)
     cfg = get_smoke("jamba-1.5-large-398b")
+    cfg = dataclasses.replace(cfg, capacity_factor=cfg.num_experts
+                              / cfg.experts_per_token)
     params = materialize(lm.param_defs(cfg), jax.random.key(0))
     B, S = 1, 8
     tok = jax.random.randint(jax.random.key(3), (B, S), 0, cfg.vocab_size)
@@ -110,6 +117,54 @@ def test_prefill_decode_consistency_hybrid():
     # bf16 SSD accumulation differs slightly between chunked & stepwise forms
     # (~0.16 max logit gap on jax 0.4.37 CPU)
     assert float(jnp.max(jnp.abs(lg - full[:, -1, :]))) < 0.20
+
+
+def _moe_slot_by_slot(cfg, p, xf):
+    """The capacity-bounded MoE written out: slots in token order, an
+    expert's slots past its capacity dropped."""
+    import math
+    T, (E, k) = xf.shape[0], (cfg.num_experts, cfg.experts_per_token)
+    C = max(1, math.ceil(T * k * cfg.capacity_factor / E))
+    w = {n: p[n].astype(jnp.float32) for n in ("router", "we_gate", "we_up", "we_down")}
+    gates, eidx = jax.lax.top_k(jax.nn.softmax(xf @ w["router"], axis=-1), k)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    seen = [0] * E
+    rows = []
+    for t in range(T):
+        y = jnp.zeros(xf.shape[1])
+        for j in range(k):
+            e = int(eidx[t, j])
+            if seen[e] < C:
+                h = jax.nn.silu(xf[t] @ w["we_gate"][e]) * (xf[t] @ w["we_up"][e])
+                y = y + gates[t, j] * (h @ w["we_down"][e])
+            seen[e] += 1
+        rows.append(y)
+    return jnp.stack(rows), C
+
+
+@pytest.mark.parametrize("tokens", ["repeated", "random"])
+def test_hybrid_moe_drops_the_slots_past_capacity(tokens):
+    """Jamba's MoE at its own capacity factor keeps each expert's first C
+    slots in token order and drops the rest: eight copies of one token all
+    pick the same two experts, so the last 8 - C get nothing."""
+    from repro.models import layers
+    cfg = get_smoke("jamba-1.5-large-398b")
+    assert cfg.act == "silu" and cfg.router_scoring != "sigmoid"
+    p = materialize(layers.moe_param_defs(cfg), jax.random.key(0))
+    if tokens == "repeated":
+        x = jnp.tile(jax.random.normal(jax.random.key(1), (1, 1, cfg.d_model)),
+                     (1, 8, 1))
+    else:
+        x = jax.random.normal(jax.random.key(1), (1, 8, cfg.d_model))
+    with jax.default_matmul_precision("highest"):
+        y, _, _ = layers.moe(cfg, p, x)
+        want, C = _moe_slot_by_slot(cfg, p, x[0])
+    y = y[0].astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(y - want))) <= 1e-5 * float(jnp.max(jnp.abs(want)))
+    if tokens == "repeated":
+        assert C < 8
+        assert float(jnp.min(jnp.abs(y[:C]).max(axis=-1))) > 0
+        assert float(jnp.max(jnp.abs(y[C:]))) == 0.0
 
 
 def test_scan_vs_unrolled_forward_match():
