@@ -14,12 +14,14 @@ the shape the Pallas kernel (kernels/flash_attention.py) implements on TPU.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamDef
@@ -432,8 +434,7 @@ def moe_v3(cfg: ModelConfig, p: Dict, x: jax.Array,
         w = w * cfg.routed_scaling_factor
         load = jnp.sum(jax.nn.one_hot(eidx, E, dtype=f32), axis=(0, 1))
         aux = seq_aux_loss(scores.reshape(B, S, E), eidx.reshape(B, S, K), E)
-    with jax.named_scope("moe.routed"):
-        y = routed_experts(cfg, p, xf, eidx, w)
+    y = routed_experts(cfg, p, xf, eidx, w)       # scopes itself: "moe.routed"
     if cfg.num_shared_experts:
         with jax.named_scope("moe.shared"):
             y = y + shared_experts(cfg, p, xf)
@@ -451,45 +452,90 @@ def seq_aux_loss(scores: jax.Array, eidx: jax.Array, E: int) -> jax.Array:
     return jnp.mean(jnp.sum(lax.stop_gradient(f) * P, axis=-1))
 
 
+_GMM_OUT = "routed_gmm_out"
+
+
+def routed_capacity(cfg: ModelConfig, slots):
+    """Rows of the routed experts' dispatch and combine for ``slots``
+    token-slots (T*K): twice the held experts' even share of them, rounded
+    up to 512 rows, and at most ``slots``. From shapes alone; ``slots`` is
+    an int, or an int array for the router state's count of fallbacks."""
+    even = -(-slots * cfg.held_experts // cfg.num_experts)
+    cap = -(-2 * even // 512) * 512
+    return min(slots, cap) if isinstance(slots, int) else jnp.minimum(slots, cap)
+
+
 def routed_experts(cfg: ModelConfig, p: Dict, xf: jax.Array, eidx: jax.Array,
                    w: jax.Array) -> jax.Array:
     """The held experts' part of the routed output, dropless.
 
-    Every token-slot (T*K of them, the worst case) is sorted by its expert:
-    the slots of held experts first, grouped in expert order, then the rest.
-    Grouped matmuls (``lax.ragged_dot``) run each group through its expert,
-    so no slot is dropped under any imbalance. Each slot's output, times
-    its weight, is added to its token.
+    Every token-slot (T*K of them) is sorted by its expert: the slots of
+    held experts first, grouped in expert order, then the rest. Grouped
+    matmuls (``lax.ragged_dot``) run each group through its expert, and
+    each slot's output, times its weight, is added to its token.
+
+    Only the held slots are live, about T*K*G/E of them, so the dispatch
+    and combine run over the first C rows of the sort, C =
+    ``routed_capacity(cfg, T*K)``, with no (T*K, ...) array. A layer-step
+    whose held slots exceed C runs the same code over all T*K rows instead
+    (``lax.cond``): no slot is dropped under any imbalance, and both row
+    counts give the same values and gradients. Where C = T*K there is one
+    path and no branch.
 
     The rows past the groups are left undefined by the TPU's grouped
     matmul, in its output and in its gradient for the rows, so each grouped
     matmul's rows past the groups are zeroed on the way in (which zeroes
     that gradient) and on the way out.
     """
-    T, D = xf.shape
-    K = eidx.shape[1]
+    T, K = eidx.shape
     G, lo = cfg.held_experts, cfg.expert_offset
+    C = routed_capacity(cfg, T * K)
+    with jax.named_scope("moe.routed"):
+        local = eidx.reshape(-1) - lo
+        key = jnp.where((local >= 0) & (local < G), local, G)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(key, G, dtype=jnp.int32), axis=0)
+        cd = jnp.dtype(cfg.compute_dtype)
+        we = {k: p[k].astype(cd) for k in ("we_gate", "we_up", "we_down")}
+        fits = jnp.sum(sizes) <= C
+    args = (we, xf, w, order, sizes)
+    if C == T * K:
+        return _routed_rows(cfg, C, *args)
+    # A conditional hands its backward the residuals of both branches, so
+    # each keeps few: the capacity path its grouped matmuls' outputs, the
+    # full-size path nothing (it recomputes its forward in the backward).
+    # The conditional stays outside the scope: on the device it spans the
+    # branch it runs, whose ops carry the scope.
+    keep_gmm = jax.checkpoint_policies.save_only_these_names(_GMM_OUT)
+    within = jax.checkpoint(functools.partial(_routed_rows, cfg, C), policy=keep_gmm)
+    full = jax.checkpoint(functools.partial(_routed_rows, cfg, T * K))
+    return lax.cond(fits, within, full, *args)
+
+
+@jax.named_scope("moe.routed")
+def _routed_rows(cfg: ModelConfig, rows: int, p: Dict, xf: jax.Array,
+                 w: jax.Array, order: jax.Array, sizes: jax.Array) -> jax.Array:
+    """``routed_experts`` over the first ``rows`` rows of the sort, which
+    hold every held slot: each held expert's SwiGLU over its group, and
+    each live row, times its weight, added to its token in a (T, D)
+    float32 result. The rows past the groups (``live`` false) are zeroed
+    around each grouped matmul."""
+    T, D = xf.shape
+    K = w.shape[1]
     cd = jnp.dtype(cfg.compute_dtype)
-    local = eidx.reshape(-1) - lo
-    held = (local >= 0) & (local < G)
-    key = jnp.where(held, local, G)
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(jax.nn.one_hot(key, G, dtype=jnp.int32), axis=0)
-    live = (jnp.arange(T * K) < jnp.sum(sizes))[:, None]
-    xs = xf[order // K].astype(cd)                             # (T*K, D)
+    slot = order[:rows]
+    live = jnp.arange(rows) < jnp.sum(sizes)
 
     def gmm(a, wt):
-        a = jnp.where(live, a, jnp.zeros((), a.dtype))
-        y = lax.ragged_dot(a, wt.astype(cd), sizes, preferred_element_type=cd)
-        return jnp.where(live, y, jnp.zeros((), cd))
+        a = jnp.where(live[:, None], a, jnp.zeros((), a.dtype))
+        y = lax.ragged_dot(a, wt, sizes, preferred_element_type=cd)
+        return checkpoint_name(jnp.where(live[:, None], y, jnp.zeros((), cd)), _GMM_OUT)
 
+    xs = xf[slot // K].astype(cd)                              # (rows, D)
     h = jax.nn.silu(gmm(xs, p["we_gate"]).astype(f32)) * gmm(xs, p["we_up"])
-    ys = gmm(h.astype(cd), p["we_down"])                       # (T*K, D)
-    # back to token order: slot j of token t sits at row inv[t*K + j]
-    inv = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    wt = jnp.where(held, w.reshape(-1), 0.0).reshape(T, K)
-    return jnp.einsum("tkd,tk->td", ys[inv].reshape(T, K, D).astype(f32), wt)
+    ys = gmm(h.astype(cd), p["we_down"])                       # (rows, D)
+    wt = jnp.where(live, w.reshape(-1)[slot], 0.0)
+    return jnp.zeros((T, D), f32).at[slot // K].add(ys.astype(f32) * wt[:, None])
 
 
 def shared_experts(cfg: ModelConfig, p: Dict, xf: jax.Array) -> jax.Array:

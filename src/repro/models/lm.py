@@ -551,24 +551,32 @@ def text_xent(cfg: ModelConfig, params: Dict, x: jax.Array,
 # ---------------------------------------------------------------------------
 
 def init_router_state(cfg: ModelConfig) -> Dict:
-    """The training step's state: each MoE layer's correction bias (R, E)
-    and a running count of the token-slots routed to each held expert
-    (R, held). Empty for a model without sigmoid routing."""
+    """The training step's state: each MoE layer's correction bias (R, E),
+    a running count of the token-slots routed to each held expert (R,
+    held), and of the layer-steps whose held slots exceeded the routed
+    experts' capacity (``layers.routed_capacity``), which took the
+    full-size path (R,). Empty for a model without sigmoid routing."""
     if cfg.router_scoring != "sigmoid":
         return {}
     R = num_repeats(cfg)
     return {"bias": jnp.zeros((R, cfg.num_experts), f32),
-            "routed": jnp.zeros((R, cfg.held_experts), jnp.int32)}
+            "routed": jnp.zeros((R, cfg.held_experts), jnp.int32),
+            "fallbacks": jnp.zeros((R,), jnp.int32)}
 
 
 def update_router_state(cfg: ModelConfig, state: Dict,
                         load: Optional[jax.Array]) -> Dict:
     """DeepSeek-V3's bias rule, b_i += gamma * sign(mean load - load_i), on
-    the load of every expert; the held experts' slots join the count."""
+    the load of every expert; the held experts' slots join the count, and
+    a layer whose held slots exceed the capacity of its slots counts a
+    fallback."""
     if not state:
         return state
     mean = jnp.mean(load, axis=-1, keepdims=True)
     lo = cfg.expert_offset
+    held = load[:, lo:lo + cfg.held_experts].astype(jnp.int32)
+    cap = L.routed_capacity(cfg, jnp.sum(load, axis=-1).astype(jnp.int32))
     return {"bias": state["bias"] + cfg.bias_update_rate * jnp.sign(mean - load),
-            "routed": state["routed"]
-            + load[:, lo:lo + cfg.held_experts].astype(jnp.int32)}
+            "routed": state["routed"] + held,
+            "fallbacks": state["fallbacks"]
+            + (jnp.sum(held, axis=-1) > cap).astype(jnp.int32)}

@@ -95,7 +95,9 @@ def make_xr_step(cfg, loss_fn, lr_fn, max_grad_norm: float = 1.0):
 def make_lm_step(cfg, lr_fn, max_grad_norm: float = 1.0):
     """LM/VLM step: (params, router_state, opt, batch, step) -> ... The
     router state (``lm.init_router_state``) takes the step's MoE load; the
-    metrics carry its running count of routed slots (``routed_slots``)."""
+    metrics carry its running counts of routed slots (``routed_slots``) and
+    of layer-steps on the routed experts' full-size path
+    (``routed_fallbacks``)."""
     from repro.models import lm
 
     def step_fn(params, state, opt_state, batch, step):
@@ -110,6 +112,7 @@ def make_lm_step(cfg, lr_fn, max_grad_norm: float = 1.0):
         metrics = dict(metrics, loss=loss, grad_norm=gnorm)
         if "routed" in state:
             metrics["routed_slots"] = state["routed"]
+            metrics["routed_fallbacks"] = state["fallbacks"]
         return params, state, opt_state, metrics
 
     return jax.jit(step_fn, donate_argnums=(0, 1, 2))
