@@ -20,8 +20,8 @@ first one that fails:
 
 ``--four-chips`` runs only the path ``launch/train.py`` shards: llama3.2-1b
 at full width on the mesh ``make_mesh_from_devices`` builds from four
-chips, for 3 steps, with the step-0 loss checked against the same params
-and batch run forward on one chip.
+chips, through ``train.loop.run_lm_training`` for 3 steps, with the step-0
+loss checked against the same params and batch run forward on one chip.
 
 Weights and data are random, made from ``SEED``. Times printed are from one
 smoke run, compilation included where marked; they are not a benchmark.
@@ -344,46 +344,43 @@ def run_pricing():
 # ---------------------------------------------------------------------------
 
 def run_four_chip_lm(batch: int = 8, seq: int = 128, steps: int = 3):
-    """``launch/train.py``'s defaults (batch 8, seq 128) at full width."""
+    """``launch/train.py``'s path at its defaults (batch 8, seq 128) and full
+    width: parameters placed by ``place_params``, trained through
+    ``train.loop.run_lm_training`` inside the mesh."""
     from repro.launch import mesh as mesh_mod
-    from repro.launch import train
-    from repro.models import lm
+    from repro.launch.train import place_params
+    from repro.models import lm, params as params_mod
     from repro.sharding import use_mesh
-    from repro.train import optim
+    from repro.train import loop
 
     require(jax.device_count() == 4,
             f"--four-chips needs 4 devices, found {jax.device_count()}")
     cfg = get_config("llama3.2-1b")
     pdefs = lm.param_defs(cfg)
     params = materialize(pdefs, jax.random.key(SEED))
-    data = synthetic.token_batches(batch, seq, cfg.vocab_size, seed=SEED)
-    batches = [{k: jnp.asarray(v) for k, v in next(data)[0].items()}
-               for _ in range(steps)]
+    data = lambda: synthetic.token_batches(batch, seq, cfg.vocab_size, seed=SEED)
+    first = {k: jnp.asarray(v) for k, v in next(data())[0].items()}
 
-    one_chip = float(jax.jit(partial(lm.lm_loss, cfg))(params, batches[0])[0])
+    one_chip = float(jax.jit(partial(lm.loss_and_load, cfg))(params, first)[0])
     mesh = mesh_mod.make_mesh_from_devices(jax.devices())
-    print(f"llama3.2-1b {cfg.param_count():,} params, batch {batch}x{seq}, "
+    print(f"llama3.2-1b {params_mod.count(pdefs):,} params, batch {batch}x{seq}, "
           f"mesh {dict(zip(mesh.axis_names, mesh.devices.shape))}")
-    losses, step_s = [], []
+    step_s = []
     with use_mesh(mesh):
-        params, opt_state, err, out_sh = train.shard_train_state(
-            pdefs, params, mesh)
-        step_fn = train.make_train_step(
-            cfg, optim.cosine_schedule(3e-4, warmup=1, total=steps), out_sh)
-        for step, b in enumerate(batches):
-            t0 = time.monotonic()
-            params, opt_state, err, loss = step_fn(
-                params, opt_state, err, b, jnp.asarray(step))
-            losses.append(float(loss))
-            step_s.append(time.monotonic() - t0)
+        res = loop.run_lm_training(
+            cfg, place_params(pdefs, params), lm.init_router_state(cfg),
+            data(), steps=steps, lr=3e-4,
+            hooks=loop.TrainHooks(heartbeat=lambda step, dt: step_s.append(dt),
+                                  log_every=0))
+    losses = res.losses
     print(f"llama3.2-1b sharded losses {losses}; one-chip step-0 loss "
           f"{one_chip}")
     print(f"llama3.2-1b smoke step times: first (compile included) "
           f"{step_s[0]:.3f} s, then {[round(t, 4) for t in step_s[1:]]} s; "
           f"peak memory per device "
           f"{[peak_bytes(d) for d in jax.devices()]}")
-    require(all(math.isfinite(x) for x in losses),
-            f"non-finite sharded loss {losses}")
+    require(len(losses) == steps and all(math.isfinite(x) for x in losses),
+            f"sharded run ended early or non-finite: {losses}")
     rel = abs(losses[0] - one_chip) / abs(one_chip)
     print(f"step-0 loss, sharded vs one chip: relative difference {rel:.3e} "
           f"(limit {TOL_LM_LOSS:.0e})")

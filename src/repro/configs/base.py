@@ -184,23 +184,6 @@ class ModelConfig:
             return True                      # uniform sliding window (mistral)
         return i % self.local_global_period != self.local_global_period - 1
 
-    def param_count(self) -> int:
-        """Analytic parameter count (used for 6ND roofline cross-check)."""
-        if self.mla or self.vision_layers:
-            raise NotImplementedError("count params.count(lm.param_defs(cfg))")
-        V, D, L = self.vocab_size, self.d_model, self.num_layers
-        total = V * D                        # input embedding
-        if not self.tie_embeddings:
-            total += V * D                   # output head
-        for i in range(L):
-            total += self._layer_params(i)
-        if self.encoder_layers:
-            for _ in range(self.encoder_layers):
-                attn = D * (self.q_dim + 2 * self.kv_dim) + self.q_dim * D
-                mlp = 2 * D * self.d_ff + self.d_ff * D
-                total += attn + mlp + 2 * D
-        return total
-
     def active_param_count(self) -> int:
         """Params touched per token (MoE: only routed experts)."""
         if self.mla or self.vision_layers:

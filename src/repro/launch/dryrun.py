@@ -27,7 +27,9 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.models.params import abstract, logical_axes
 from repro.sharding import fix_divisibility, spec_tree, use_mesh
-from repro.train import optim
+from repro.train import loop, optim
+
+LR_SCHEDULE = optim.cosine_schedule(3e-4, warmup=100, total=10_000)
 
 
 def force_host_devices():
@@ -40,45 +42,27 @@ def force_host_devices():
     os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")   # silence SPMD warnings
 
 
-def _opt_state_abstract(params_abs):
-    f32 = jnp.float32
-    zeros = lambda p: jax.ShapeDtypeStruct(p.shape, f32)
-    return optim.AdamWState(jax.tree.map(zeros, params_abs),
-                            jax.tree.map(zeros, params_abs),
-                            jax.ShapeDtypeStruct((), jnp.int32))
-
-
 def build_step(cfg, shape_name: str):
-    """(step_fn, abstract inputs dict, logical-axes dict, donate, out_axes)
-    for the cell. ``out_axes``: logical axes for the step OUTPUTS — pinning
-    them makes GSPMD lower fsdp gradient reductions as reduce-scatter
-    instead of all-reduce (§Perf cell B, iteration B3)."""
+    """(step_fn, abstract inputs dict, logical-axes dict, donate) for the
+    cell. The train step is ``train.loop.lm_step``, whose new parameters and
+    moments keep their logical layout, so GSPMD lowers the fsdp gradient
+    reductions as reduce-scatter instead of all-reduce (§Perf cell B,
+    iteration B3)."""
     _, _, kind = SHAPES[shape_name]
     pdefs = lm.param_defs(cfg)
     params_abs, params_ax = abstract(pdefs), logical_axes(pdefs)
 
     if kind == "train":
-        lr_fn = optim.cosine_schedule(3e-4, 100, 10_000)
-
-        def train_step(params, opt_state, batch, step):
-            (loss, _), grads = jax.value_and_grad(
-                lm.lm_loss, has_aux=True, argnums=1)(cfg, params, batch)
-            grads, _ = optim.clip_by_global_norm(grads, 1.0)
-            params, opt_state = optim.adamw_update(
-                grads, opt_state, params, lr=lr_fn(step))
-            return params, opt_state, loss
-
-        opt_abs = _opt_state_abstract(params_abs)
-        opt_ax = optim.AdamWState(params_ax, params_ax, ())
-        batch_abs = mesh_mod.input_specs(cfg, shape_name)
-        batch_ax = mesh_mod.input_axes(cfg, shape_name)
-        args = dict(params=params_abs, opt_state=opt_abs, batch=batch_abs,
+        state_abs = jax.eval_shape(partial(lm.init_router_state, cfg))
+        opt_abs = jax.eval_shape(optim.adamw_init, params_abs)
+        args = dict(params=params_abs, state=state_abs, opt_state=opt_abs,
+                    batch=mesh_mod.input_specs(cfg, shape_name),
                     step=jax.ShapeDtypeStruct((), jnp.int32))
-        axes = dict(params=params_ax, opt_state=opt_ax, batch=batch_ax,
-                    step=())
-        out_axes = (params_ax, opt_ax, ())
-        out_abs = (params_abs, opt_abs, jax.ShapeDtypeStruct((), jnp.float32))
-        return train_step, args, axes, (0, 1), (out_axes, out_abs)
+        axes = dict(params=params_ax,
+                    state=jax.tree.map(lambda a: (None,) * a.ndim, state_abs),
+                    opt_state=optim.AdamWState(params_ax, params_ax, ()),
+                    batch=mesh_mod.input_axes(cfg, shape_name), step=())
+        return loop.lm_step(cfg, LR_SCHEDULE), args, axes, (0, 1, 2)
 
     if kind == "prefill":
         def prefill_step(params, batch):
@@ -90,7 +74,7 @@ def build_step(cfg, shape_name: str):
         batch_abs = mesh_mod.input_specs(cfg, shape_name)
         batch_ax = mesh_mod.input_axes(cfg, shape_name)
         return (prefill_step, dict(params=params_abs, batch=batch_abs),
-                dict(params=params_ax, batch=batch_ax), (), None)
+                dict(params=params_ax, batch=batch_ax), ())
 
     # decode
     def serve_step(params, cache, batch):
@@ -103,8 +87,7 @@ def build_step(cfg, shape_name: str):
     batch_ax = mesh_mod.input_axes(cfg, shape_name)
     return (serve_step, dict(params=params_abs, cache=cache_abs,
                              batch=batch_abs),
-            dict(params=params_ax, cache=cache_ax, batch=batch_ax), (1,),
-            None)
+            dict(params=params_ax, cache=cache_ax, batch=batch_ax), (1,))
 
 
 def _scaled_cfg(cfg, repeats: int, enc_layers=None):
@@ -120,17 +103,12 @@ def _scaled_cfg(cfg, repeats: int, enc_layers=None):
 
 
 def _compile_cell(cfg, shape_name, mesh, rules):
-    step_fn, args, axes, donate, outs = build_step(cfg, shape_name)
+    step_fn, args, axes, donate = build_step(cfg, shape_name)
     shardings = fix_divisibility(spec_tree(axes, mesh, rules), args)
-    kw = {}
-    if outs is not None:
-        out_axes, out_abs = outs
-        kw["out_shardings"] = fix_divisibility(
-            spec_tree(out_axes, mesh, rules), out_abs)
     with use_mesh(mesh, rules):
         jitted = jax.jit(step_fn,
                          in_shardings=tuple(shardings[k] for k in args),
-                         donate_argnums=donate, **kw)
+                         donate_argnums=donate)
         lowered = jitted.lower(*[args[k] for k in args])
         compiled = lowered.compile()
     return compiled

@@ -485,12 +485,6 @@ def xent_loss(logits: jax.Array, labels: jax.Array,
     return jnp.mean(nll)
 
 
-def lm_loss(cfg: ModelConfig, params: Dict, batch: Dict,
-            aux_weight: float = 0.01) -> Tuple[jax.Array, Dict]:
-    total, (_, metrics) = loss_and_load(cfg, params, batch, aux_weight=aux_weight)
-    return total, metrics
-
-
 def loss_and_load(cfg: ModelConfig, params: Dict, batch: Dict,
                   router_bias: Optional[jax.Array] = None,
                   aux_weight: float = 0.01) -> Tuple[jax.Array, Tuple]:

@@ -122,15 +122,28 @@ def named_sharding(logical: Sequence[Optional[str]]) -> Optional[NamedSharding]:
     return NamedSharding(mesh, resolve_spec(logical))
 
 
+def _named_tree(axes_tree):
+    return jax.tree.map(
+        named_sharding, axes_tree,
+        is_leaf=lambda x: isinstance(x, tuple) and all(
+            a is None or isinstance(a, str) for a in x))
+
+
 def spec_tree(axes_tree, mesh: Mesh, rules: Optional[Dict[str, Axes]] = None):
     """Resolve a pytree of logical-axis tuples into NamedShardings."""
     with use_mesh(mesh, rules):
-        return jax.tree.map(
-            lambda axes: named_sharding(axes),
-            axes_tree,
-            is_leaf=lambda x: isinstance(x, tuple) and all(
-                a is None or isinstance(a, str) for a in x),
-        )
+        return _named_tree(axes_tree)
+
+
+def constrain(tree, axes_tree):
+    """Pin each array of ``tree`` to the sharding its logical axes
+    (``axes_tree``) resolve to under the bound mesh and rules, less the mesh
+    axes that do not divide its dimensions (``fix_divisibility``). No-op
+    without a mesh."""
+    if _ctx() is None:
+        return tree
+    shardings = fix_divisibility(_named_tree(axes_tree), tree)
+    return jax.tree.map(jax.lax.with_sharding_constraint, tree, shardings)
 
 
 def fix_divisibility(shardings, abstract_tree):

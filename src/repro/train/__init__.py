@@ -1,1 +1,1 @@
-from repro.train import checkpoint, compress, loop, optim
+from repro.train import checkpoint, loop, optim

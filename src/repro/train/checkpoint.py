@@ -123,6 +123,8 @@ def restore(ckpt_dir: str, tree_like, step: Optional[int] = None,
     for (path, like), sh in zip(paths, shard_leaves):
         key = _SEP.join(_key_str(p) for p in path)
         arr = data[key]
+        if arr.dtype.kind == "V":       # npz keeps bfloat16 as raw 2-byte void
+            arr = arr.view(like.dtype)
         if sh is not None:
             leaves.append(jax.device_put(arr, sh))
         else:

@@ -8,6 +8,10 @@ BatchNorm statistics or an MoE router's correction bias and routed count
 resume-from-latest, loader-state capture, a preemption hook, and a per-step
 heartbeat for straggler monitoring (DESIGN.md §7).
 ``run_xr_training`` and ``run_lm_training`` differ only in their step.
+``lm_step`` is the one LM training step: the launcher (``launch/train.py``)
+trains through ``run_lm_training`` on its mesh, and the dry-run
+(``launch/dryrun.py``) lowers ``lm_step`` for the production mesh. A resumed
+run skips its loader past the checkpoint's loader index (``_skip_to``).
 
 The loop keeps one batch of device-side prefetch: once step k is
 dispatched, the loader is called for step k+1 and that batch's copy to the
@@ -33,6 +37,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import itertools
 import signal
 import time
 from dataclasses import dataclass, field
@@ -41,7 +46,7 @@ from typing import Callable, Dict, Iterator, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import spans
+from repro import sharding, spans
 from repro.train import checkpoint as ckpt_mod
 from repro.train import optim
 
@@ -92,13 +97,19 @@ def make_xr_step(cfg, loss_fn, lr_fn, max_grad_norm: float = 1.0):
     return jax.jit(step_fn, donate_argnums=(0, 1, 2))
 
 
-def make_lm_step(cfg, lr_fn, max_grad_norm: float = 1.0):
-    """LM/VLM step: (params, router_state, opt, batch, step) -> ... The
-    router state (``lm.init_router_state``) takes the step's MoE load; the
-    metrics carry its running counts of routed slots (``routed_slots``) and
-    of layer-steps on the routed experts' full-size path
-    (``routed_fallbacks``)."""
+def lm_step(cfg, lr_fn, max_grad_norm: float = 1.0):
+    """The LM/VLM step, unjitted: (params, router_state, opt, batch, step)
+    -> ... The router state (``lm.init_router_state``) takes the step's MoE
+    load; the metrics carry its running counts of routed slots
+    (``routed_slots``) and of layer-steps on the routed experts' full-size
+    path (``routed_fallbacks``). Under a bound mesh the new parameters and
+    moments keep the layout their logical axes give (as the launcher
+    places the parameters), so the next step reuses the compiled program;
+    without one the step carries no sharding."""
     from repro.models import lm
+    from repro.models.params import logical_axes
+
+    axes = logical_axes(lm.param_defs(cfg))
 
     def step_fn(params, state, opt_state, batch, step):
         spans.RECORDER.count(STEP_TRACES)     # once per trace
@@ -108,6 +119,9 @@ def make_lm_step(cfg, lr_fn, max_grad_norm: float = 1.0):
         grads, gnorm = optim.clip_by_global_norm(grads, max_grad_norm)
         params, opt_state = optim.adamw_update(
             grads, opt_state, params, lr=lr_fn(step))
+        params = sharding.constrain(params, axes)
+        opt_state = opt_state._replace(m=sharding.constrain(opt_state.m, axes),
+                                       v=sharding.constrain(opt_state.v, axes))
         state = lm.update_router_state(cfg, state, load)
         metrics = dict(metrics, loss=loss, grad_norm=gnorm)
         if "routed" in state:
@@ -115,7 +129,13 @@ def make_lm_step(cfg, lr_fn, max_grad_norm: float = 1.0):
             metrics["routed_fallbacks"] = state["fallbacks"]
         return params, state, opt_state, metrics
 
-    return jax.jit(step_fn, donate_argnums=(0, 1, 2))
+    return step_fn
+
+
+def make_lm_step(cfg, lr_fn, max_grad_norm: float = 1.0):
+    """``lm_step``, jitted, with the parameters, router state and optimizer
+    state donated."""
+    return jax.jit(lm_step(cfg, lr_fn, max_grad_norm), donate_argnums=(0, 1, 2))
 
 
 def _schedule(lr: float, steps: int):
@@ -154,7 +174,8 @@ def _train(step_fn, params, state, batches: Iterator, *, steps: int,
 
     if ckpt_dir and resume and ckpt_mod.latest_step(ckpt_dir) is not None:
         tree = {"params": params, "state": state, "opt": opt_state}
-        tree, start, extra = ckpt_mod.restore(ckpt_dir, tree)
+        tree, start, extra = ckpt_mod.restore(
+            ckpt_dir, tree, shardings=jax.tree.map(lambda x: x.sharding, tree))
         params, state, opt_state = tree["params"], tree["state"], tree["opt"]
         batches = _skip_to(batches, extra.get("loader_idx", 0))
 
@@ -248,6 +269,7 @@ def _warn_straggler(step: int, dt: float, med: float, children, retraced: bool):
 
 
 def _skip_to(batches: Iterator, loader_idx: int) -> Iterator:
-    """Loader state restore: synthetic loaders are pure in idx, so skipping
-    is O(1) — they accept start_idx; for generic iterators we fast-forward."""
-    return batches
+    """Resume a loader at a checkpoint: drop its items until the index it
+    yields passes ``loader_idx``, the index the checkpoint's last batch
+    carried. A loader built to start there has nothing dropped."""
+    return itertools.dropwhile(lambda item: item[1] <= loader_idx, batches)

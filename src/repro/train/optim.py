@@ -10,6 +10,8 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import sharding
+
 f32 = jnp.float32
 
 
@@ -20,10 +22,13 @@ class AdamWState(NamedTuple):
 
 
 def adamw_init(params) -> AdamWState:
-    zeros = lambda p: jnp.zeros(p.shape, f32)
+    """Zero moments, each with its parameter's sharding (``zeros_like``
+    keeps a committed array's), and a step count replicated over the bound
+    mesh, if one is bound (``repro.sharding``)."""
+    zeros = lambda p: jnp.zeros_like(p, dtype=f32)
     return AdamWState(jax.tree.map(zeros, params),
                       jax.tree.map(zeros, params),
-                      jnp.zeros((), jnp.int32))
+                      jnp.zeros((), jnp.int32, device=sharding.named_sharding(())))
 
 
 def adamw_update(grads, state: AdamWState, params, *, lr, b1=0.9, b2=0.95,

@@ -122,3 +122,21 @@ def test_checkpoints_keep_the_consumed_batch_loader_index(tmp_path):
     assert ckpt.latest_step(preempted) == 3
     _, _, extra = ckpt.restore(preempted, {"params": _weights()[0]})
     assert extra["loader_idx"] == 6
+
+
+def test_a_resumed_run_skips_a_fresh_loader_past_the_checkpoint(tmp_path):
+    """Resumed from the checkpoint after step 2 (loader index 4) with a
+    loader built from its start, the loop drops batches 0 and 1 and trains
+    step 2 on batch 2 (index 6), not on batch 0 again. A loader built to
+    start at the checkpoint has nothing dropped."""
+    d = str(tmp_path)
+    _run(_batches(), 2, ckpt_dir=d, ckpt_every=2)
+    drawn = []
+    res = _run(_counted(drawn), 4, ckpt_dir=d, ckpt_every=3)
+    assert res.step == 4 and len(res.losses) == 2
+    assert drawn == [2, 4, 6, 8]
+    assert ckpt.latest_step(d) == 3
+    assert ckpt.restore(d, {})[2] == {"loader_idx": 6}
+
+    started = ((None, i) for i in itertools.count(6, 2))
+    assert next(loop._skip_to(started, 4)) == (None, 6)

@@ -127,7 +127,7 @@ def test_dryrun_machinery_tiny_mesh():
     old = C.SHAPES["train_4k"]
     C.SHAPES["train_4k"] = (32, 2, "train")
     try:
-        step_fn, args, axes, donate, _outs = dryrun.build_step(cfg, "train_4k")
+        step_fn, args, axes, donate = dryrun.build_step(cfg, "train_4k")
         shardings = fix_divisibility(spec_tree(axes, mesh, None), args)
         with use_mesh(mesh):
             compiled = jax.jit(
@@ -143,28 +143,97 @@ def test_dryrun_machinery_tiny_mesh():
         C.SHAPES["train_4k"] = old
 
 
-def test_trainer_steps_reuse_one_compiled_program():
-    """launch/train.py's step returns the state in the layout it was given
-    (pinned out_shardings), so step 1 does not compile the step again."""
+def _capture_lm_steps(monkeypatch):
+    """The jitted steps ``train.loop`` builds, as the benchmark wraps them."""
+    from repro.train import loop
+    jitted, make = [], loop.make_lm_step
+    monkeypatch.setattr(loop, "make_lm_step",
+                        lambda *a, **kw: jitted.append(make(*a, **kw)) or jitted[-1])
+    return jitted
+
+
+def test_trainer_steps_reuse_one_compiled_program(monkeypatch):
+    """The LM step keeps the parameters and moments in the layout the
+    launcher placed them in, so step 1 does not compile the step again."""
     from repro.configs import get_smoke
     from repro.data import synthetic
     from repro.launch import mesh as mesh_mod, train
     from repro.models import lm
     from repro.models.params import materialize
-    from repro.train import optim
+    from repro.train import loop
 
     cfg = get_smoke("llama3.2-1b")
     pdefs = lm.param_defs(cfg)
+    jitted = _capture_lm_steps(monkeypatch)
     mesh = mesh_mod.make_mesh_from_devices()
-    data = synthetic.token_batches(2, 32, cfg.vocab_size)
     with sh.use_mesh(mesh):
-        params, opt, err, out_sh = train.shard_train_state(
-            pdefs, materialize(pdefs, jax.random.key(0)), mesh)
-        step_fn = train.make_train_step(
-            cfg, optim.cosine_schedule(3e-4, 1, 3), out_sh)
-        for step in range(3):
-            batch = {k: jnp.asarray(v) for k, v in next(data)[0].items()}
-            params, opt, err, loss = step_fn(params, opt, err, batch,
-                                             jnp.asarray(step))
-    assert np.isfinite(float(loss))
-    assert step_fn._cache_size() == 1
+        params = train.place_params(pdefs, materialize(pdefs, jax.random.key(0)))
+        res = loop.run_lm_training(
+            cfg, params, lm.init_router_state(cfg),
+            synthetic.token_batches(2, 32, cfg.vocab_size), steps=3,
+            hooks=loop.TrainHooks(log_every=0))
+    assert len(jitted) == 1 and jitted[0]._cache_size() == 1
+    assert len(res.losses) == 3 and np.isfinite(res.losses).all()
+    leaves = jax.tree.leaves
+    for p, m, v in zip(leaves(res.params), leaves(res.opt_state.m),
+                       leaves(res.opt_state.v), strict=True):
+        assert isinstance(p.sharding, NamedSharding)
+        assert m.sharding == p.sharding and v.sharding == p.sharding
+
+
+def test_launcher_trains_through_the_shared_loop_and_resumes(tmp_path,
+                                                              monkeypatch):
+    """``launch/train.py`` trains through ``train.loop``: its steps record
+    the loop's spans, its checkpoint keeps the loader index, and a second
+    call resumes after the checkpoint, in the layout the first one saved
+    from, so each call compiles its step once."""
+    import time
+    from repro import spans
+    from repro.launch import train
+    from repro.train import checkpoint as ckpt
+
+    # the persistent compile cache would stay on in this test process
+    monkeypatch.setattr(train, "enable_compile_cache", lambda: None)
+    argv = ["--arch", "llama3.2-1b", "--smoke", "--batch", "2", "--seq", "32",
+            "--ckpt-dir", str(tmp_path), "--ckpt-every", "3"]
+
+    def steps_run(steps):
+        lo = time.time_ns()
+        train.main(argv + ["--steps", str(steps)])
+        return [k for _, _, name, k in spans.RECORDER.events(lo, time.time_ns())
+                if name == "train.step"]
+
+    jitted = _capture_lm_steps(monkeypatch)
+    assert steps_run(3) == [0, 1, 2]
+    assert ckpt.latest_step(str(tmp_path)) == 3
+    assert ckpt.restore(str(tmp_path), {})[2] == {"loader_idx": 6}
+    assert steps_run(5) == [3, 4]
+    assert [f._cache_size() for f in jitted] == [1, 1]
+
+
+def test_dryrun_train_step_is_the_trainer_step():
+    """On one batch, on one device with no mesh, the dry-run's train step
+    and ``make_lm_step`` give bitwise-equal loss and parameters."""
+    from repro.configs import get_smoke
+    from repro.data import synthetic
+    from repro.launch import dryrun
+    from repro.models import lm
+    from repro.models.params import materialize
+    from repro.train import loop, optim
+
+    cfg = get_smoke("mixtral-8x7b")
+    batch = {k: jnp.asarray(v) for k, v in
+             next(synthetic.token_batches(2, 32, cfg.vocab_size))[0].items()}
+
+    def inputs():          # fresh each call: make_lm_step donates them
+        params = materialize(lm.param_defs(cfg), jax.random.key(0))
+        return (params, lm.init_router_state(cfg), optim.adamw_init(params),
+                batch, jnp.asarray(150))
+
+    dry = jax.jit(dryrun.build_step(cfg, "train_4k")[0])(*inputs())
+    ours = loop.make_lm_step(cfg, dryrun.LR_SCHEDULE)(*inputs())
+    assert set(dry[3]) == set(ours[3])
+    np.testing.assert_array_equal(dry[3]["loss"], ours[3]["loss"])
+    for a, b in zip(jax.tree.leaves(dry[0]), jax.tree.leaves(ours[0]),
+                    strict=True):
+        np.testing.assert_array_equal(a, b)

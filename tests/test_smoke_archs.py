@@ -54,7 +54,7 @@ def test_one_train_step_reduces_loss_direction(arch):
     @jax.jit
     def step(params, opt):
         (loss, _), grads = jax.value_and_grad(
-            lm.lm_loss, has_aux=True, argnums=1)(cfg, params, batch)
+            lm.loss_and_load, has_aux=True, argnums=1)(cfg, params, batch)
         grads, _ = optim.clip_by_global_norm(grads, 1.0)
         params, opt = optim.adamw_update(grads, opt, params, lr=1e-3)
         return params, opt, loss
